@@ -143,6 +143,9 @@ def main(argv=None) -> None:
                     help="also write results as a machine-readable perf "
                          "trajectory (e.g. BENCH_spgemm.json)")
     args = ap.parse_args(argv)
+    from repro.kernels._compat import use_compile_cache
+    use_compile_cache(str(pathlib.Path(__file__).resolve().parents[1]
+                          / ".jax_cache"))
     only = set(args.only.split(",")) if args.only else None
     print("name,us_per_call,derived")
     failures = 0

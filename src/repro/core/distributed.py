@@ -51,7 +51,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from .formats import CSR
 from .plan import SpGEMMPlan, plan_spgemm, structure_key, cache_lookup, \
@@ -299,7 +299,7 @@ def _build_1d_fn(mesh: Mesh, axis: str, masked: bool, statics: dict,
         ((P(axis), P(axis), P(axis)) if with_sched else ()) + \
         ((P(axis),) * 6 if with_pb else ())
     return shard_map(local, mesh=mesh, in_specs=in_specs,
-                     out_specs=P(axis), check_rep=False)
+                     out_specs=P(axis), check_vma=False)
 
 
 # ----------------------------------------------------------------------------
@@ -697,7 +697,7 @@ def spmm_1d(mesh: Mesh, a_sh: ShardedCSR, x: jax.Array,
         return spmm(a_loc, x_rep)[None]
 
     fn = shard_map(local, mesh=mesh, in_specs=(P(axis), P()),
-                   out_specs=P(axis), check_rep=False)
+                   out_specs=P(axis), check_vma=False)
     return _gather_rows(fn(a_sh.parts, x), a_sh)
 
 
@@ -946,7 +946,7 @@ def _build_summa_fn(plan: SummaPlan, mesh: Mesh, axis: str):
     in_specs = (P(axis), P(axis), P(), P(axis), P(axis), P()) + \
         ((P(axis), P(axis), P(axis)) if with_sched else ())
     return shard_map(local, mesh=mesh, in_specs=in_specs,
-                     out_specs=P(axis), check_rep=False)
+                     out_specs=P(axis), check_vma=False)
 
 
 def plan_spgemm_summa(a: CSR, b: CSR, n_shards: int,
@@ -1211,7 +1211,7 @@ def _build_pb_summa_fn(plan: PBSummaPlan, mesh: Mesh, axis: str):
     in_specs = (P(axis), P(axis), P(), P(axis), P(axis), P()) + \
         (P(axis),) * 8
     return shard_map(local, mesh=mesh, in_specs=in_specs,
-                     out_specs=P(axis), check_rep=False)
+                     out_specs=P(axis), check_vma=False)
 
 
 def plan_spgemm_pb_summa(a: CSR, b: CSR, n_shards: int,

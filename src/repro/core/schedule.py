@@ -35,12 +35,12 @@ def _acc_dtype():
     return jnp.int64 if jax.config.jax_enable_x64 else jnp.int32
 
 
-def guard_i32_flop(flop, n_bins: int = 1, what: str = "rows_to_bins"):
+def guard_i32_flop(flop, what: str = "rows_to_bins"):
     """Fail loudly instead of mis-binning on int32 prefix-sum overflow.
 
-    The equal-flop partition multiplies the *total* flop by bin ids up to
-    ``n_bins - 1`` before dividing, so the quantity that must fit int32 is
-    ``total * (n_bins - 1)``, not just the total.  Three regimes:
+    The quantity that must fit int32 is the *total* flop (the last entry
+    of the prefix sum; :func:`rows_to_bins` forms its partition targets
+    without multiplying the total).  Three regimes:
 
       * x64 enabled: accumulation is promoted to int64 -- nothing to guard;
       * concrete ``flop`` (the planner's eager path): check exactly in
@@ -54,11 +54,11 @@ def guard_i32_flop(flop, n_bins: int = 1, what: str = "rows_to_bins"):
     if isinstance(flop, jax.core.Tracer):
         return
     total = int(np.asarray(flop, dtype=np.int64).sum())
-    if total * max(n_bins - 1, 1) > _I32_MAX:
+    if total > _I32_MAX:
         raise OverflowError(
-            f"{what}: total flop {total} (x {max(n_bins - 1, 1)} partition "
-            f"targets) overflows the int32 prefix sum; enable "
-            f"jax_enable_x64 or shard the product (DESIGN.md section 9)")
+            f"{what}: total flop {total} overflows the int32 prefix sum; "
+            f"enable jax_enable_x64 or shard the product (DESIGN.md "
+            f"section 9)")
 
 
 def flops_per_row(a: CSR, b: CSR) -> jax.Array:
@@ -107,11 +107,14 @@ def rows_to_bins(flop: jax.Array, n_bins: int) -> jax.Array:
     # Exact arithmetic without float64: int32 accumulation is exact below
     # 2^31 (the proxy-scale regime, DESIGN.md section 9); the guard raises
     # on concrete inputs that would wrap, and x64 promotes to int64.
-    guard_i32_flop(flop, n_bins, "rows_to_bins")
+    guard_i32_flop(flop, "rows_to_bins")
     acc = _acc_dtype()
     ps = prefix_sum(flop.astype(acc))
     total = ps[-1]
-    targets = (total * jnp.arange(1, n_bins, dtype=acc)) // n_bins
+    k = jnp.arange(1, n_bins, dtype=acc)
+    # floor(total * k / n_bins) without forming total * k, which wraps
+    # int32 long before the total does (400M flop at G500 scale 16)
+    targets = (total // n_bins) * k + ((total % n_bins) * k) // n_bins
     # ps is over row *boundaries*; bin b starts at the first row whose
     # cumulative flop reaches target b.
     cuts = lowbnd(ps[1:], targets + 1)
@@ -129,7 +132,7 @@ def bin_row_assignment(offsets: jax.Array, n_rows: int) -> jax.Array:
 
 def bin_flop(flop: jax.Array, offsets: jax.Array) -> jax.Array:
     """Total flop per bin (n_bins,) -- the balance metric."""
-    guard_i32_flop(flop, 1, "bin_flop")
+    guard_i32_flop(flop, "bin_flop")
     ps = prefix_sum(flop.astype(_acc_dtype()))
     return ps[offsets[1:]] - ps[offsets[:-1]]
 

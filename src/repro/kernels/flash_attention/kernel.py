@@ -24,7 +24,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import _compat
 
 NEG_INF = -1e30
 
@@ -106,7 +105,7 @@ def fwd_call(batch: int, n_heads: int, n_kv_heads: int, seq_q: int,
                         pltpu.VMEM((bq,), jnp.float32),
                         pltpu.VMEM((bq, d), jnp.float32)],
         interpret=interpret,
-        compiler_params=_compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
     )

@@ -14,6 +14,7 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import _compat
 from . import kernel as K
 
 
@@ -21,8 +22,7 @@ def flash_attention(q, k, v, *, causal: bool = True, scale: float | None = None,
                     bq: int = 128, bkv: int = 128,
                     interpret: bool | None = None):
     """q: (B, H, Sq, D); k, v: (B, Hkv, Skv, D) -> (B, H, Sq, D)."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = _compat.resolve_interpret(interpret)
     b, h, sq, d = q.shape
     _, hkv, skv, _ = k.shape
     bq = min(bq, sq)

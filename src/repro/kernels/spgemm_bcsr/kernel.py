@@ -43,10 +43,82 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import _compat
+from repro.kernels.spgemm_hash.kernel import EMPTY, HASH_CONST
 
-from repro.kernels.spgemm_hash.kernel import (_View, _probe_scalar,
-                                              _probe_vector, EMPTY)
+#: Vector probe width (keys compared per step in vector mode).
+CHUNK = 8
+
+
+def _hash(key: jax.Array, mask: jax.Array) -> jax.Array:
+    return (key * HASH_CONST) & mask
+
+
+def _probe_scalar(tkey_ref, key, table_size):
+    """Linear probing (Fig. 8a): return slot holding `key` or first empty.
+
+    ``table_size`` may be a static int or a traced per-bin scalar; either
+    way it must be a power of two.  The probed key rides in the loop carry
+    so the cond never reads the ref.
+    """
+    mask = jnp.int32(table_size) - 1
+
+    def cond(state):
+        _, k = state
+        return (k != key) & (k != EMPTY)
+
+    def body(state):
+        idx, _ = state
+        nidx = (idx + 1) & mask
+        return nidx, tkey_ref[nidx]
+
+    idx0 = _hash(key, mask)
+    idx, _ = jax.lax.while_loop(cond, body, (idx0, tkey_ref[idx0]))
+    return idx
+
+
+def _probe_vector(tkey_ref, key, table_size):
+    """Chunked probing (Fig. 8b): compare CHUNK table entries per step.
+
+    The hash addresses a *chunk*; within a chunk, hit/empty lanes are found
+    with a masked iota-min.  Falls through to the next chunk on a full miss
+    (linear probing over chunks).  ``table_size`` is >= CHUNK.
+    """
+    cmask = jnp.int32(table_size) // CHUNK - 1
+    lane = jax.lax.broadcasted_iota(jnp.int32, (CHUNK,), 0)
+    BIG = CHUNK + 1
+
+    def load(chunk_id):
+        return tkey_ref[pl.ds(chunk_id * CHUNK, CHUNK)]
+
+    def cond(state):
+        _, ks = state
+        return ~jnp.any((ks == key) | (ks == EMPTY))
+
+    def body(state):
+        chunk_id, _ = state
+        nid = (chunk_id + 1) & cmask
+        return nid, load(nid)
+
+    c0 = _hash(key, cmask)
+    chunk_id, ks = jax.lax.while_loop(cond, body, (c0, load(c0)))
+    hit_lane = jnp.min(jnp.where(ks == key, lane, BIG))
+    empty_lane = jnp.min(jnp.where(ks == EMPTY, lane, BIG))
+    lane_id = jnp.where(hit_lane < BIG, hit_lane, empty_lane)
+    return chunk_id * CHUNK + lane_id
+
+
+class _View:
+    """1-D view of a ref's row ``lead`` so ``_block_row_loop`` runs
+    unchanged on batched operands (``ref[lead, i]``)."""
+
+    def __init__(self, ref, lead):
+        self._ref, self._lead = ref, lead
+
+    def __getitem__(self, i):
+        return self._ref[self._lead, i]
+
+    def __setitem__(self, i, v):
+        self._ref[self._lead, i] = v
 
 
 def _block_row_loop(i, *, indptr_a_ref, indptr_b_ref, a_bcol_ref, a_blk_ref,
@@ -158,7 +230,7 @@ def numeric_call(n_bins: int, gm: int, bcap_a: int, bcap_b: int, bcap_c: int,
         out_shape=[jax.ShapeDtypeStruct((bcap_c,), jnp.int32),
                    jax.ShapeDtypeStruct((bcap_c, bm, bn), jnp.float32)],
         interpret=interpret,
-        compiler_params=_compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
     ))
 
@@ -247,6 +319,6 @@ def batched_numeric_call(n_members: int, n_bins: int, gm: int, bcap_a: int,
                    jax.ShapeDtypeStruct((n_members, bcap_c, bm, bn),
                                         jnp.float32)],
         interpret=interpret,
-        compiler_params=_compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
     ))

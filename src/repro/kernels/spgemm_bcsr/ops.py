@@ -31,7 +31,9 @@ from jax import custom_batching
 
 from repro.core.formats import CSR, BCSR
 import repro.core.schedule as sched
+from repro.kernels import _compat
 from repro.kernels.spgemm_hash import kernel as HK
+from repro.kernels.spgemm_hash import ops as hash_ops
 from . import kernel as K
 
 #: Trace-time dispatch counters (see module docstring).
@@ -69,8 +71,6 @@ def bcsr_inspect(a: BCSR, b: BCSR, *, n_bins: int = 8, vector: bool = False,
     guard can fire on concrete inputs (the planner's path).
     """
     KERNEL_CALLS["symbolic"] += 1
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     pa, pb = _pattern_csr(a), _pattern_csr(b)
     gm = pa.n_rows
 
@@ -85,10 +85,9 @@ def bcsr_inspect(a: BCSR, b: BCSR, *, n_bins: int = 8, vector: bool = False,
 
     # Phase 1 (symbolic): exact block-nnz per block row of C, via the
     # scalar hash symbolic kernel on the block patterns.
-    sym = HK.symbolic_call(n_bins, gm, pa.cap, pb.cap, table_size, vector,
-                           interpret)
-    row_nnzb = sym(offsets, bin_tsize, pa.indptr, pb.indptr,
-                   pa.indices, pa.data, pb.indices, pb.data)
+    row_nnzb = hash_ops.spgemm_hash_symbolic(
+        pa, pb, vector=vector, table_size=table_size,
+        interpret=interpret, schedule=(offsets, bin_tsize))
     indptr_cb = sched.prefix_sum(row_nnzb).astype(jnp.int32)
     return flop, offsets, bin_tsize, table_size, row_nnzb, indptr_cb
 
@@ -136,8 +135,7 @@ def spgemm_bcsr(a: BCSR, b: BCSR, bcap_c: int, *, n_bins: int = 8,
     additionally skips the symbolic kernel -- the planned execute path
     stages the MXU numeric kernel alone.
     """
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = _compat.resolve_interpret(interpret)
     bm, bk = a.block
     bk2, bn = b.block
     assert bk == bk2 and a.shape[1] == b.shape[0], (a.block, b.block)

@@ -2,36 +2,50 @@
 
 Faithful structure, TPU-resident state:
 
-  * grid = equal-flop row bins from ``core.schedule`` (C1; Fig. 6) -- the
-    Pallas grid replaces the OpenMP static thread pool;
-  * per-program hash table in **VMEM scratch** (C5: thread-private memory,
-    sized once per worker to the max per-row flop -- Fig. 7 lines 5-14 --
-    and *reinitialized per row*, not reallocated);
-  * power-of-two table, multiply hash, linear probing (Fig. 8a);
-  * optional **vectorized probing** (C3 / Fig. 8b): the table is scanned in
-    ``CHUNK``-wide vector compares -- the VPU analogue of the AVX-512
-    chunked probe of Ross [28]; first-hit / first-empty are extracted with
-    an iota-masked min instead of x86 ``ctz``;
+  * grid = (members, equal-flop row bins from ``core.schedule``) (C1;
+    Fig. 6) -- the Pallas grid replaces the OpenMP static thread pool.  A
+    plain product is the one-member case; ``vmap`` fleets and row shards
+    are the many-member case of the same kernel;
+  * per-program hash table in **VMEM scratch** laid out ``(rows, 128)``
+    (C5: thread-private memory, sized once to the max per-row flop --
+    Fig. 7 lines 5-14 -- and *reinitialized per row*, not reallocated);
+  * power-of-two table, multiply hash (Fig. 8).  A probe step loads one
+    128-lane table row and finds the key or the first empty lane with an
+    iota-masked ``min`` (the TPU stand-in for x86 ``ctz``):
+
+      - ``vector=False`` is linear probing (Fig. 8a): the hash names a
+        *slot*; the first step only considers lanes at or after it, later
+        steps whole rows, so slots are visited in exactly the order
+        ``h, h+1, ...`` of the scalar algorithm;
+      - ``vector=True`` is chunked probing (Fig. 8b): the hash names a
+        *row* (a chunk of ``min(tsize, 128)`` slots) and any lane of it may
+        take the key;
   * two phases: ``symbolic`` counts nnz per row, ``numeric`` fills values
     (section 2: the two-phase method gives exact output capacity);
   * output rows are emitted **unsorted** (C8) in table-scan order; sorting
     is an explicit epilogue owned by the caller (Table 1 "Any/Select").
 
-Memory plumbing: CSR arrays ride in VMEM whole (test scale); on a real chip
-the row bins stream through double-buffered DMA windows, which changes the
-BlockSpecs but not the kernel body.  Scalar row pointers (A, B, C) and the
-bin offsets ride in SMEM via ``PrefetchScalarGridSpec`` so the control loops
-never touch VMEM.
+Memory plumbing (what the TPU compiler admits):
 
-Batched-grid variants (``batched_symbolic_call`` / ``batched_numeric_call``)
-add a leading grid dimension over fleet members: grid ``(n_members,
-n_bins)``, member operands blocked ``(1, cap)`` by BlockSpec, schedules as
-2-D prefetched scalars indexed ``[member, bin]``.  Scratch stays a single
-unbatched table (static per capacity class) because ``_row_loop``
-reinitializes it per row -- no cross-member state survives.  These are the
-kernels ``ops.py`` swaps in through a ``custom_vmap`` rule so the planned
-hash path traces under ``vmap`` (batched fleets) and ``shard_map``
-(distributed executors) with bitwise-identical per-member results.
+  * CSR operands and outputs stay in HBM (``memory_space=pl.ANY``).  The
+    kernel reads them through small SMEM *windows* that are DMA'd in when
+    an index leaves the window, and writes its output through an SMEM
+    staging window that is DMA'd out when full.  Scalars therefore only
+    ever move between SMEM and the scalar unit; VMEM is touched only by
+    whole 128-lane rows of the hash table.
+  * 1-D HBM arrays are tiled by ``ALIGN`` words, so every DMA starts at a
+    multiple of ``ALIGN`` and moves a multiple of it (``kernels._smem``).
+    ``ops.py`` pads the operands to whole windows; output streams are
+    padded likewise and sliced back by ``ops.py``.
+  * Only the bin schedule (``offsets``, ``bin_tsize``) is scalar-prefetched,
+    so SMEM use is independent of the matrix size.
+  * Bins of one member run in order on one core (``"arbitrary"``), and the
+    output of bin ``b + 1`` starts where bin ``b`` stopped (rows are
+    contiguous), so one output stream per member runs across its bins.
+
+The A side reaches the kernel pre-gathered per A entry: ``a_bs[j] =
+indptr_b[a_idx[j]]`` and ``a_bl[j]`` (that B row's length), so no random
+access into ``indptr_b`` happens in the kernel.
 """
 from __future__ import annotations
 
@@ -42,13 +56,22 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import _compat
+from repro.kernels._smem import Stream, Window
 
 #: Knuth multiplicative constant (wraps mod 2^32; int32 two's complement).
 HASH_CONST = -1640531527   # == 2654435761 mod 2^32 (Python int -> inlined literal)
 
-#: Vector probe width (lanes compared per step in hash_vector mode).
+#: Smallest admissible table (and the per-bin floor of ``bin_tsize``).
 CHUNK = 8
+
+#: Lanes of one table row: the width of one probe step.
+LANES = 128
+
+#: SMEM window sizes (words): the A-entry group (and the indptr of A), the
+#: B-row group, and the output staging window.
+WIN_A = 2048
+WIN_B = 1024
+WIN_OUT = 2048
 
 EMPTY = -1
 
@@ -57,378 +80,273 @@ def _hash(key: jax.Array, mask: jax.Array) -> jax.Array:
     return (key * HASH_CONST) & mask
 
 
-def _probe_scalar(tkey_ref, key, table_size):
-    """Linear probing (Fig. 8a): return slot holding `key` or first empty.
+def stream_len(n: int) -> int:
+    """Length of an output stream of ``n`` words: whole staging windows."""
+    return max(-(-n // WIN_OUT) * WIN_OUT, WIN_OUT)
 
-    ``table_size`` may be a static int or a traced per-bin scalar (Fig. 7
-    lines 9-12: each bin probes only its own power-of-two-sized prefix of
-    the scratch table); either way it must be a power of two.
 
-    The probed key rides in the loop carry so the cond never reads the ref
-    (older jax cannot discharge ref reads in a while cond under interpret
-    mode; on TPU the two spellings lower identically).
+def table_rows(table_size: int) -> int:
+    """Rows of the ``(rows, LANES)`` scratch table for a p2 ``table_size``."""
+    return max(table_size // LANES, 1)
+
+
+def _lane():
+    return jax.lax.broadcasted_iota(jnp.int32, (1, LANES), 1)
+
+
+def _clear(tkey_ref, tval_ref, n_rows, rows):
+    """Reset the table prefix ``[0, n_rows)`` rows: keys EMPTY, values 0
+    (eight rows per store where the ``rows``-row scratch has them)."""
+    def put(sl):
+        tkey_ref[sl, :] = jnp.full((sl.size, LANES), EMPTY, jnp.int32)
+        if tval_ref is not None:
+            tval_ref[sl, :] = jnp.zeros((sl.size, LANES), jnp.float32)
+
+    n8 = n_rows // 8 if rows >= 8 else jnp.int32(0)
+
+    def blk(r, c):
+        put(pl.ds(pl.multiple_of(r * 8, 8), 8))
+        return c
+
+    def one(r, c):
+        put(pl.ds(r, 1))
+        return c
+
+    if rows >= 8:
+        jax.lax.fori_loop(0, n8, blk, 0)
+    jax.lax.fori_loop(n8 * 8, n_rows, one, 0)
+
+
+def _probe(tkey_ref, key, tsz, row_mask, vector):
+    """Find ``key`` or the slot it should take; returns ``(row, lane, new)``.
+
+    ``tsz`` is the (traced, p2) effective table size; tables narrower than
+    a row use the first ``tsz`` lanes of row 0.  ``row_mask`` is the static
+    scratch row mask, which keeps every row index provably in bounds.
     """
-    mask = jnp.int32(table_size) - 1
+    lane = _lane()
+    live = lane < jnp.minimum(tsz, LANES)
+    rmask = jnp.maximum(tsz >> 7, 1) - 1
+    if vector:
+        r0, first = _hash(key, rmask), jnp.int32(0)
+    else:
+        h = _hash(key, tsz - 1)
+        r0, first = h >> 7, h & (LANES - 1)
 
-    def cond(state):
-        _, k = state
-        return (k != key) & (k != EMPTY)
+    def cond(st):
+        return st[2] >= LANES
 
-    def body(state):
-        idx, _ = state
-        nidx = (idx + 1) & mask
-        return nidx, tkey_ref[nidx]
+    def body(st):
+        r, it, _, _ = st
+        row = tkey_ref[pl.ds(r & row_mask, 1), :]
+        elig = live & (lane >= jnp.where(it == 0, first, 0))
+        # one cross-lane min per step: a hit scores its lane, an empty
+        # slot its lane + LANES, anything else 2 * LANES -- so the min is
+        # the hit if there is one, else the first empty lane (+ LANES),
+        # else 2 * LANES (row full: probe on)
+        score = jnp.where(elig & (row == key), lane,
+                          jnp.where(elig & (row == EMPTY), lane + LANES,
+                                    2 * LANES))
+        s = jnp.min(score)
+        found = jnp.where(s < 2 * LANES, s & (LANES - 1), LANES)
+        nxt = jnp.where(found < LANES, r, (r + 1) & rmask)
+        return nxt, it + 1, found, (s >= LANES).astype(jnp.int32)
 
-    idx0 = _hash(key, mask)
-    idx, _ = jax.lax.while_loop(cond, body, (idx0, tkey_ref[idx0]))
-    return idx
-
-
-def _probe_vector(tkey_ref, key, table_size):
-    """Chunked probing (Fig. 8b): compare CHUNK table entries per step.
-
-    The hash addresses a *chunk*; within a chunk, hit/empty lanes are found
-    with a masked iota-min (TPU stand-in for ``__builtin_ctz``).  Falls
-    through to the next chunk on a full miss (linear probing over chunks).
-    ``table_size`` may be static or a traced per-bin scalar (>= CHUNK).
-    """
-    cmask = jnp.int32(table_size) // CHUNK - 1
-    lane = jax.lax.broadcasted_iota(jnp.int32, (CHUNK,), 0)
-    BIG = CHUNK + 1
-
-    def load(chunk_id):
-        return pl.load(tkey_ref, (pl.ds(chunk_id * CHUNK, CHUNK),))
-
-    # chunk contents ride in the carry: no ref reads in the while cond
-    # (same interpret-mode constraint as _probe_scalar).
-    def cond(state):
-        _, ks = state
-        return ~jnp.any((ks == key) | (ks == EMPTY))
-
-    def body(state):
-        chunk_id, _ = state
-        nid = (chunk_id + 1) & cmask
-        return nid, load(nid)
-
-    c0 = _hash(key, cmask)
-    chunk_id, ks = jax.lax.while_loop(cond, body, (c0, load(c0)))
-    hit_lane = jnp.min(jnp.where(ks == key, lane, BIG))
-    empty_lane = jnp.min(jnp.where(ks == EMPTY, lane, BIG))
-    lane_id = jnp.where(hit_lane < BIG, hit_lane, empty_lane)
-    return chunk_id * CHUNK + lane_id
+    r, _, found, new = jax.lax.while_loop(
+        cond, body, (r0, jnp.int32(0), jnp.int32(LANES), jnp.int32(0)))
+    return r & row_mask, found, new
 
 
-def _row_loop(i, *, indptr_a_ref, indptr_b_ref, a_idx_ref, a_val_ref,
-              b_idx_ref, b_val_ref, tkey_ref, tval_ref, tsize, vector,
-              numeric):
-    """Fig. 1 inner loops for one output row, hash accumulation.
-
-    ``tsize`` is this bin's effective table size (Fig. 7 lines 9-12: a
-    power of two <= the static scratch allocation); probes never leave the
-    ``[0, tsize)`` prefix, so slots past it stay EMPTY and cost nothing but
-    the vectorized whole-table reinit.
-    """
-    probe = _probe_vector if vector else _probe_scalar
-    # Fig. 7: "reuses that hash table ... by reinitializing for each row".
-    tkey_ref[...] = jnp.full_like(tkey_ref, EMPTY)
+def _hash_kernel(off_ref, tsz_ref, *refs, numeric, vector, n_bins, m,
+                 table_size, strides, lens):
+    n_in = 6 if numeric else 4
+    n_out = 2 if numeric else 1
+    ins, outs = refs[:n_in], refs[n_in:n_in + n_out]
+    scratch = refs[n_in + n_out:]
     if numeric:
-        tval_ref[...] = jnp.zeros_like(tval_ref)
+        ip_hbm, bs_hbm, bl_hbm, av_hbm, bi_hbm, bv_hbm = ins
+        (ip_w, bs_w, bl_w, av_w, bi_w, bv_w, stage_k, stage_v, wbase,
+         sstate, tkey_ref, tval_ref, sems) = scratch
+        a_group = ([bs_hbm, bl_hbm, av_hbm], [bs_w, bl_w, av_w])
+        b_group = ([bi_hbm, bv_hbm], [bi_w, bv_w])
+        stage = [stage_k, stage_v]
+    else:
+        ip_hbm, bs_hbm, bl_hbm, bi_hbm = ins
+        ip_w, bs_w, bl_w, bi_w, stage_k, wbase, sstate, tkey_ref, sems = \
+            scratch
+        tval_ref = None
+        a_group = ([bs_hbm, bl_hbm], [bs_w, bl_w])
+        b_group = ([bi_hbm], [bi_w])
+        stage = [stage_k]
+    s_sched, s_ip, s_a, s_b, s_out = strides
+    l_ip, l_a, l_b = lens
+    row_mask = table_rows(table_size) - 1
 
-    def do_a(j, inserted):
-        k = a_idx_ref[j]
-        av = a_val_ref[j] if numeric else jnp.float32(0)
-
-        def do_b(t, inserted):
-            c = b_idx_ref[t]
-            slot = probe(tkey_ref, c, tsize)
-            is_new = tkey_ref[slot] == EMPTY
-            tkey_ref[slot] = c
-            if numeric:
-                # NB the backend is free to contract this into an FMA (one
-                # rounding per probe -- the host LLVM backend does, matching
-                # the paper's AVX-512 FMA kernels).  Cross-oracle bitwise
-                # claims therefore hold for exactly-representable arithmetic
-                # (the dyadic fuzz values); against per-product-rounding
-                # references (jnp twin, scipy) real-valued results may
-                # differ by 1 ulp per accumulated product.
-                tval_ref[slot] = tval_ref[slot] + av * b_val_ref[t]
-            return inserted + is_new.astype(jnp.int32)
-
-        return jax.lax.fori_loop(indptr_b_ref[k], indptr_b_ref[k + 1], do_b,
-                                 inserted)
-
-    return jax.lax.fori_loop(indptr_a_ref[i], indptr_a_ref[i + 1], do_a,
-                             jnp.int32(0))
-
-
-def _symbolic_kernel(offsets_ref, tsize_ref, indptr_a_ref, indptr_b_ref,
-                     a_idx_ref, a_val_ref, b_idx_ref, b_val_ref,
-                     row_nnz_ref, tkey_ref, *, table_size, vector):
-    b = pl.program_id(0)
-    # per-bin effective table size (prefetched; clamped to the allocation)
-    tsz = jnp.minimum(tsize_ref[b], jnp.int32(table_size))
-
-    def do_row(i, _):
-        cnt = _row_loop(
-            i, indptr_a_ref=indptr_a_ref, indptr_b_ref=indptr_b_ref,
-            a_idx_ref=a_idx_ref, a_val_ref=a_val_ref, b_idx_ref=b_idx_ref,
-            b_val_ref=b_val_ref, tkey_ref=tkey_ref, tval_ref=None,
-            tsize=tsz, vector=vector, numeric=False)
-        row_nnz_ref[i] = cnt
-        return 0
-
-    jax.lax.fori_loop(offsets_ref[b], offsets_ref[b + 1], do_row, 0)
-
-
-def _numeric_kernel(offsets_ref, tsize_ref, indptr_a_ref, indptr_b_ref,
-                    indptr_c_ref, a_idx_ref, a_val_ref, b_idx_ref, b_val_ref,
-                    out_idx_ref, out_val_ref, tkey_ref, tval_ref, *,
-                    table_size, vector):
-    b = pl.program_id(0)
-    tsz = jnp.minimum(tsize_ref[b], jnp.int32(table_size))
-
-    @pl.when(b == 0)
-    def _init():
-        out_idx_ref[...] = jnp.zeros_like(out_idx_ref)
-        out_val_ref[...] = jnp.zeros_like(out_val_ref)
-
-    def do_row(i, _):
-        _row_loop(
-            i, indptr_a_ref=indptr_a_ref, indptr_b_ref=indptr_b_ref,
-            a_idx_ref=a_idx_ref, a_val_ref=a_val_ref, b_idx_ref=b_idx_ref,
-            b_val_ref=b_val_ref, tkey_ref=tkey_ref, tval_ref=tval_ref,
-            tsize=tsz, vector=vector, numeric=True)
-        # Flush occupied slots in table order -> **unsorted** columns (C8).
-        # Only this bin's [0, tsz) prefix can be occupied, so the scan stops
-        # there -- the per-bin sizing win the paper gets from Fig. 7 line 10.
-        base = indptr_c_ref[i]
-
-        def flush(s, cnt):
-            key = tkey_ref[s]
-            occupied = key != EMPTY
-            pos = base + cnt
-            # masked single-element store: padded lane writes are dropped by
-            # writing to the (guaranteed-live) same slot when unoccupied.
-            @pl.when(occupied)
-            def _():
-                out_idx_ref[pos] = key
-                out_val_ref[pos] = tval_ref[s]
-            return cnt + occupied.astype(jnp.int32)
-
-        jax.lax.fori_loop(0, tsz, flush, jnp.int32(0))
-        return 0
-
-    jax.lax.fori_loop(offsets_ref[b], offsets_ref[b + 1], do_row, 0)
-
-
-# ---------------------------------------------------------------------------
-# pallas_call builders
-# ---------------------------------------------------------------------------
-
-def _full(spec_len):
-    # index_map receives (grid idx, *scalar_prefetch_refs) under
-    # PrefetchScalarGridSpec; the whole array is one block for all programs.
-    return pl.BlockSpec((spec_len,), lambda b, *prefetch: (0,))
-
-
-@functools.lru_cache(maxsize=256)
-def symbolic_call(n_bins: int, m: int, cap_a: int, cap_b: int,
-                  table_size: int, vector: bool, interpret: bool):
-    """Cached builder: a stable callable per static config, jit-wrapped so
-    repeat invocations hit the dispatch cache instead of retracing (the
-    paper's C5 allocate-once discipline applied to compilation).
-
-    Call signature of the returned function:
-    ``(offsets, bin_tsize, indptr_a, indptr_b, a_idx, a_val, b_idx, b_val)``
-    where ``bin_tsize`` holds each bin's power-of-two effective table size
-    (Fig. 7 lines 9-12); ``table_size`` stays the static scratch allocation
-    (the bin max), so the grid and scratch shapes never depend on the data.
-    """
-    kernel = functools.partial(_symbolic_kernel, table_size=table_size,
-                               vector=vector)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,           # offsets, bin_tsize, indptr_a/b
-        grid=(n_bins,),
-        in_specs=[_full(cap_a), _full(cap_a), _full(cap_b), _full(cap_b)],
-        out_specs=_full(m),
-        scratch_shapes=[pltpu.VMEM((table_size,), jnp.int32)],
-    )
-    return jax.jit(pl.pallas_call(
-        kernel, grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((m,), jnp.int32),
-        interpret=interpret,
-        compiler_params=_compat.CompilerParams(
-            dimension_semantics=("arbitrary",)),
-    ))
-
-
-@functools.lru_cache(maxsize=256)
-def numeric_call(n_bins: int, m: int, cap_a: int, cap_b: int, cap_c: int,
-                 table_size: int, vector: bool, interpret: bool):
-    kernel = functools.partial(_numeric_kernel, table_size=table_size,
-                               vector=vector)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=5,   # offsets, bin_tsize, indptr_a/b, indptr_c
-        grid=(n_bins,),
-        in_specs=[_full(cap_a), _full(cap_a), _full(cap_b), _full(cap_b)],
-        out_specs=[_full(cap_c), _full(cap_c)],
-        scratch_shapes=[pltpu.VMEM((table_size,), jnp.int32),
-                        pltpu.VMEM((table_size,), jnp.float32)],
-    )
-    return jax.jit(pl.pallas_call(
-        kernel, grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct((cap_c,), jnp.int32),
-                   jax.ShapeDtypeStruct((cap_c,), jnp.float32)],
-        interpret=interpret,
-        compiler_params=_compat.CompilerParams(
-            dimension_semantics=("arbitrary",)),
-    ))
-
-
-# ---------------------------------------------------------------------------
-# batched grid: one extra grid dimension over fleet members / row shards
-# ---------------------------------------------------------------------------
-
-class _View:
-    """1-D view of a ref's row ``lead`` so ``_row_loop`` runs unchanged.
-
-    Member operands arrive as ``(1, cap)`` BlockSpec blocks (lead 0) and
-    schedules as full 2-D prefetched scalars (lead = member id); either way
-    the row/probe loops only ever see ``ref[lead, i]``.
-    """
-
-    def __init__(self, ref, lead):
-        self._ref, self._lead = ref, lead
-
-    def __getitem__(self, i):
-        return self._ref[self._lead, i]
-
-    def __setitem__(self, i, v):
-        self._ref[self._lead, i] = v
-
-
-def _batched_symbolic_kernel(offsets_ref, tsize_ref, indptr_a_ref,
-                             indptr_b_ref, a_idx_ref, a_val_ref, b_idx_ref,
-                             b_val_ref, row_nnz_ref, tkey_ref, *,
-                             table_size, vector):
-    e = pl.program_id(0)                      # fleet member / row shard
-    b = pl.program_id(1)                      # equal-flop row bin
-    tsz = jnp.minimum(tsize_ref[e, b], jnp.int32(table_size))
-    out = _View(row_nnz_ref, 0)
-
-    def do_row(i, _):
-        cnt = _row_loop(
-            i, indptr_a_ref=_View(indptr_a_ref, e),
-            indptr_b_ref=_View(indptr_b_ref, e),
-            a_idx_ref=_View(a_idx_ref, 0), a_val_ref=_View(a_val_ref, 0),
-            b_idx_ref=_View(b_idx_ref, 0), b_val_ref=_View(b_val_ref, 0),
-            tkey_ref=tkey_ref, tval_ref=None, tsize=tsz, vector=vector,
-            numeric=False)
-        out[i] = cnt
-        return 0
-
-    jax.lax.fori_loop(offsets_ref[e, b], offsets_ref[e, b + 1], do_row, 0)
-
-
-def _batched_numeric_kernel(offsets_ref, tsize_ref, indptr_a_ref,
-                            indptr_b_ref, indptr_c_ref, a_idx_ref, a_val_ref,
-                            b_idx_ref, b_val_ref, out_idx_ref, out_val_ref,
-                            tkey_ref, tval_ref, *, table_size, vector):
     e = pl.program_id(0)
     b = pl.program_id(1)
-    tsz = jnp.minimum(tsize_ref[e, b], jnp.int32(table_size))
-    ic = _View(indptr_c_ref, e)               # prefetched: full 2-D array
-    oi, ov = _View(out_idx_ref, 0), _View(out_val_ref, 0)
+    n_a, n_b = len(a_group[0]), len(b_group[0])
+    ipw = Window([ip_hbm], [ip_w], wbase, 0, sems, 0, WIN_A)
+    aw = Window(*a_group, wbase, 1, sems, 1, WIN_A)
+    bw = Window(*b_group, wbase, 2, sems, 1 + n_a, WIN_B)
+    # member e owns output words [e * s_out, (e + 1) * s_out): a numeric
+    # stream past cap_c is truncated there, never written into member e + 1
+    out = Stream(outs, stage, sstate, sems, 1 + n_a + n_b, WIN_OUT,
+                 end=(e + 1) * s_out)
+    for w in (ipw, aw, bw):
+        w.reset()
+    ip0, a0, b0 = e * s_ip, e * s_a, e * s_b
+    ip_last, a_last, b_last = (ip0 + l_ip - WIN_A, a0 + l_a - WIN_A,
+                               b0 + l_b - WIN_B)
 
     @pl.when(b == 0)
-    def _init():
-        out_idx_ref[...] = jnp.zeros_like(out_idx_ref)
-        out_val_ref[...] = jnp.zeros_like(out_val_ref)
+    def _open():
+        out.start(e * s_out)
+
+    es = e if s_sched else 0           # schedules: per member or shared
+    lo = off_ref[es * (n_bins + 1) + b]
+    hi = off_ref[es * (n_bins + 1) + b + 1]
+    tsz = jnp.minimum(tsz_ref[es * n_bins + b], jnp.int32(table_size))
+    n_rows = jnp.maximum(tsz >> 7, 1)
+    lane = _lane()
 
     def do_row(i, _):
-        _row_loop(
-            i, indptr_a_ref=_View(indptr_a_ref, e),
-            indptr_b_ref=_View(indptr_b_ref, e),
-            a_idx_ref=_View(a_idx_ref, 0), a_val_ref=_View(a_val_ref, 0),
-            b_idx_ref=_View(b_idx_ref, 0), b_val_ref=_View(b_val_ref, 0),
-            tkey_ref=tkey_ref, tval_ref=tval_ref, tsize=tsz, vector=vector,
-            numeric=True)
-        base = ic[i]
+        _clear(tkey_ref, tval_ref, n_rows, row_mask + 1)
+        (p0,) = ipw.read(ip0 + i, ip_last)
+        (p1,) = ipw.read(ip0 + i + 1, ip_last)
 
-        def flush(s, cnt):
-            key = tkey_ref[s]
-            occupied = key != EMPTY
-            pos = base + cnt
+        def do_a(j, cnt):
+            avals = aw.read(a0 + j, a_last)
+            bs, bl = avals[0], avals[1]
 
-            @pl.when(occupied)
-            def _():
-                oi[pos] = key
-                ov[pos] = tval_ref[s]
-            return cnt + occupied.astype(jnp.int32)
+            def do_b(t, cnt):
+                bvals = bw.read(b0 + t, b_last)
+                key = bvals[0]
+                r, found, new = _probe(tkey_ref, key, tsz, row_mask, vector)
+                sel = lane == found
+                row = tkey_ref[pl.ds(r, 1), :]
+                tkey_ref[pl.ds(r, 1), :] = jnp.where(sel, key, row)
+                if numeric:
+                    # one product, then one add: per-product rounding on
+                    # the vector unit (the scipy order of operations)
+                    prod = jnp.full((1, LANES), avals[2], jnp.float32) * \
+                        bvals[1]
+                    v = tval_ref[pl.ds(r, 1), :]
+                    tval_ref[pl.ds(r, 1), :] = jnp.where(sel, v + prod, v)
+                return cnt + new
 
-        jax.lax.fori_loop(0, tsz, flush, jnp.int32(0))
+            return jax.lax.fori_loop(bs, bs + bl, do_b, cnt)
+
+        cnt = jax.lax.fori_loop(p0, p1, do_a, jnp.int32(0))
+        if not numeric:
+            out.push(cnt)
+            return 0
+
+        # Flush occupied slots in table order -> **unsorted** columns (C8).
+        # Only this bin's [0, tsz) prefix can be occupied.
+        def flush_row(r, c):
+            kr = tkey_ref[pl.ds(r & row_mask, 1), :]
+            vr = tval_ref[pl.ds(r & row_mask, 1), :]
+            occ = kr != EMPTY
+            n_occ = jnp.sum(occ.astype(jnp.int32))
+
+            def take(_, prev):
+                ln = jnp.min(jnp.where(occ & (lane > prev), lane, LANES))
+                pick = lane == ln
+                out.push(jnp.sum(jnp.where(pick, kr, 0)),
+                         jnp.sum(jnp.where(pick, vr, 0.0)))
+                return ln
+
+            jax.lax.fori_loop(0, n_occ, take, jnp.int32(-1))
+            return c
+
+        jax.lax.fori_loop(0, n_rows, flush_row, 0)
         return 0
 
-    jax.lax.fori_loop(offsets_ref[e, b], offsets_ref[e, b + 1], do_row, 0)
+    jax.lax.fori_loop(lo, hi, do_row, 0)
 
-
-def _bfull(cap):
-    # one (1, cap) block per member; bins share the member's block.
-    return pl.BlockSpec((1, cap), lambda e, b, *prefetch: (e, 0))
+    @pl.when(b == n_bins - 1)
+    def _close():
+        if not numeric:
+            # rows past the last bin (padded batch members) count zero
+            def pad(_, c):
+                out.push(jnp.int32(0))
+                return c
+            jax.lax.fori_loop(hi, m, pad, 0)
+        out.flush()
 
 
 @functools.lru_cache(maxsize=256)
-def batched_symbolic_call(n_members: int, n_bins: int, m: int, cap_a: int,
-                          cap_b: int, table_size: int, vector: bool,
-                          interpret: bool):
-    """Batched-grid symbolic phase: grid ``(n_members, n_bins)``.
+def _hash_call(numeric: bool, n_members: int, n_bins: int, m: int,
+              lens: tuple, out_len: int, strides: tuple, table_size: int,
+              vector: bool, interpret: bool):
+    """Cached builder: one jitted ``pallas_call`` per static config.
 
-    Signature of the returned callable mirrors :func:`symbolic_call` with a
-    leading member axis on every operand: schedules ``(n_members, n_bins+1)``
-    / ``(n_members, n_bins)``, CSR payloads ``(n_members, cap)``, output
-    row counts ``(n_members, m)``.  The scratch table is shared across the
-    whole grid -- ``_row_loop`` reinitializes it per row, so member programs
-    cannot observe each other, and the static allocation is the capacity
-    class's bin max (per-member effective sizes still ride in as data).
+    ``lens = (l_ip, l_a, l_b)`` are the padded per-member lengths of the
+    flat operands (see :func:`padded_len`); ``out_len`` the per-member
+    output stream length (:func:`stream_len`); ``strides = (sched, ip, a,
+    b, out)`` the flat distance between consecutive members of each
+    operand group -- 0 for an operand shared by every member (the
+    schedule entry is 1 for per-member schedules, 0 for a shared one).
+
+    Operands (all flat, 1-D): ``offsets`` and ``bin_tsize`` (scalar
+    prefetch), then ``indptr_a, a_bs, a_bl[, a_val], b_idx[, b_val]`` in
+    HBM.  Returns ``row_nnz`` (symbolic) or ``(cols, vals)`` (numeric),
+    each ``n_members * out_len`` long.
     """
-    kernel = functools.partial(_batched_symbolic_kernel,
-                               table_size=table_size, vector=vector)
+    kernel = functools.partial(
+        _hash_kernel, numeric=numeric, vector=vector, n_bins=n_bins, m=m,
+        table_size=table_size, strides=strides, lens=lens)
+    smem = pltpu.SMEM
+    n_a = 3 if numeric else 2
+    n_b = 2 if numeric else 1
+    n_out = 2 if numeric else 1
+    rows = table_rows(table_size)
+    scratch = ([smem((WIN_A,), jnp.int32)]
+               + [smem((WIN_A,), jnp.int32)] * 2
+               + ([smem((WIN_A,), jnp.float32)] if numeric else [])
+               + [smem((WIN_B,), jnp.int32)]
+               + ([smem((WIN_B,), jnp.float32)] if numeric else [])
+               + [smem((WIN_OUT,), jnp.int32)]
+               + ([smem((WIN_OUT,), jnp.float32)] if numeric else [])
+               + [smem((3,), jnp.int32), smem((2,), jnp.int32),
+                  pltpu.VMEM((rows, LANES), jnp.int32)]
+               + ([pltpu.VMEM((rows, LANES), jnp.float32)] if numeric
+                  else [])
+               + [pltpu.SemaphoreType.DMA((1 + n_a + n_b + n_out,))])
+    any_spec = pl.BlockSpec(memory_space=pl.ANY)
+    n_in = 1 + n_a + n_b
+    dtypes = [jnp.int32, jnp.float32][:n_out]
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,           # offsets, bin_tsize, indptr_a/b
+        num_scalar_prefetch=2,           # offsets, bin_tsize
         grid=(n_members, n_bins),
-        in_specs=[_bfull(cap_a), _bfull(cap_a), _bfull(cap_b), _bfull(cap_b)],
-        out_specs=_bfull(m),
-        scratch_shapes=[pltpu.VMEM((table_size,), jnp.int32)],
+        in_specs=[any_spec] * n_in,
+        out_specs=[any_spec] * n_out,
+        scratch_shapes=scratch,
     )
-    return jax.jit(pl.pallas_call(
+    call = pl.pallas_call(
         kernel, grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((n_members, m), jnp.int32),
+        out_shape=[jax.ShapeDtypeStruct((n_members * out_len,), dt)
+                   for dt in dtypes],
         interpret=interpret,
-        compiler_params=_compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
-    ))
+        name="spgemm_hash_" + ("numeric" if numeric else "symbolic"),
+    )
+    return jax.jit(call)
 
 
-@functools.lru_cache(maxsize=256)
-def batched_numeric_call(n_members: int, n_bins: int, m: int, cap_a: int,
-                         cap_b: int, cap_c: int, table_size: int,
-                         vector: bool, interpret: bool):
-    """Batched-grid numeric phase; see :func:`batched_symbolic_call`."""
-    kernel = functools.partial(_batched_numeric_kernel,
-                               table_size=table_size, vector=vector)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=5,   # offsets, bin_tsize, indptr_a/b, indptr_c
-        grid=(n_members, n_bins),
-        in_specs=[_bfull(cap_a), _bfull(cap_a), _bfull(cap_b), _bfull(cap_b)],
-        out_specs=[_bfull(cap_c), _bfull(cap_c)],
-        scratch_shapes=[pltpu.VMEM((table_size,), jnp.int32),
-                        pltpu.VMEM((table_size,), jnp.float32)],
-    )
-    return jax.jit(pl.pallas_call(
-        kernel, grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct((n_members, cap_c), jnp.int32),
-                   jax.ShapeDtypeStruct((n_members, cap_c), jnp.float32)],
-        interpret=interpret,
-        compiler_params=_compat.CompilerParams(
-            dimension_semantics=("arbitrary", "arbitrary")),
-    ))
+def symbolic_call(n_members: int, n_bins: int, m: int, lens: tuple,
+                  out_len: int, strides: tuple, table_size: int,
+                  vector: bool, interpret: bool):
+    """Symbolic phase (exact nnz per row of C); see :func:`_hash_call`."""
+    return _hash_call(False, n_members, n_bins, m, lens, out_len, strides,
+                      table_size, vector, interpret)
+
+
+def numeric_call(n_members: int, n_bins: int, m: int, lens: tuple,
+                 out_len: int, strides: tuple, table_size: int,
+                 vector: bool, interpret: bool):
+    """Numeric phase (unsorted columns and values); see
+    :func:`_hash_call`."""
+    return _hash_call(True, n_members, n_bins, m, lens, out_len, strides,
+                      table_size, vector, interpret)

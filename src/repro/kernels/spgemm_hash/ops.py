@@ -28,17 +28,17 @@ kernel alone.
 Trace contexts: with a plan-frozen schedule (and static ``table_size``)
 every dynamic value is an ordinary traced array, so the planned path runs
 under ``jit``, inside ``shard_map`` bodies, and -- through a ``custom_vmap``
-rule that swaps in the batched-grid kernels of ``kernel.py`` -- under
+rule that runs the same kernel over a ``(members, bins)`` grid -- under
 ``vmap`` over fleet members.  Only the *inspection* (``hash_schedule`` with
 no pinned ``table_size``) needs concrete inputs.  ``spgemm_hash_jnp``
 remains solely as a reference oracle for differential tests and as the
 documented fallback for general semirings / masks and planless traced
 calls.
 
-Rounding contract vs the oracle: the kernel accumulates with the
-backend's fused multiply-add (one rounding per probe; the host LLVM
-backend contracts, matching the paper's AVX-512 FMA kernels), while the
-jnp twin -- like scipy -- rounds every product before summing.  Sparsity
+Rounding contract vs the oracle: the kernel forms each product and then
+adds it, as the jnp twin and scipy do, but a backend may contract the two
+into a fused multiply-add (one rounding per probe; the host LLVM backend
+does, matching the paper's AVX-512 FMA kernels).  Sparsity
 pattern, row pointers, and output ordering agree bitwise always; values
 agree bitwise whenever the arithmetic is exactly representable (the
 dyadic fuzz values), and to 1 ulp per accumulated product otherwise.
@@ -57,12 +57,15 @@ from jax import custom_batching
 
 from repro.core.formats import CSR
 import repro.core.schedule as sched
+from repro.kernels import _compat
+from repro.kernels._smem import padded_len
 from . import kernel as K
 
 #: Trace-time dispatch counters: how many times each Pallas entry point was
 #: staged into a computation (eager call or jit trace; dispatch-cache hits
 #: do not re-count).  Keys: symbolic, numeric, batched_symbolic,
-#: batched_numeric -- the ``batched_*`` entries are the vmap-rule kernels.
+#: batched_numeric -- the ``batched_*`` entries are the vmap-rule calls of
+#: the same kernel over a member grid.
 KERNEL_CALLS = {"symbolic": 0, "numeric": 0,
                 "batched_symbolic": 0, "batched_numeric": 0}
 
@@ -107,66 +110,85 @@ def hash_schedule(a: CSR, b: CSR, n_bins: int,
 
 
 # ---------------------------------------------------------------------------
-# trace-context entry points: the plain kernels, made vmappable
+# trace-context entry points: one kernel, plain or over a batched grid
 # ---------------------------------------------------------------------------
-# ``jax.vmap`` has no batching rule for a pallas_call with scalar-prefetch
-# operands whose *schedule semantics* differ per member, so each entry wraps
-# the plain 1-D-grid kernel in a ``custom_vmap`` whose rule dispatches the
-# natively batched grid of ``kernel.py`` (grid (n_members, n_bins)) instead.
-# Unbatched operands (e.g. a shared B, or a schedule override closed over by
-# a vmapped caller) are broadcast along the member axis; BlockSpec blocking
-# keeps the per-program working set at one member regardless.
+# ``jax.vmap`` has no batching rule for a pallas_call whose scalar-prefetch
+# *schedule semantics* differ per member, so each entry wraps the kernel in
+# a ``custom_vmap`` whose rule runs the same kernel over a grid
+# ``(n_members, n_bins)`` instead of ``(1, n_bins)``.  Operands that are
+# not batched (e.g. a shared B, or a schedule closed over by a vmapped
+# caller) are passed once with member stride 0; within an operand group
+# (schedule / indptr of A / A entries / B entries) a batched member forces
+# the group's other arrays to be broadcast.
 
-@functools.lru_cache(maxsize=256)
-def _symbolic_entry(n_bins: int, m: int, cap_a: int, cap_b: int,
-                    table_size: int, vector: bool, interpret: bool):
-    plain = K.symbolic_call(n_bins, m, cap_a, cap_b, table_size, vector,
-                            interpret)
-
-    @custom_batching.custom_vmap
-    def sym(offsets, bin_tsize, indptr_a, indptr_b, a_idx, a_val,
-            b_idx, b_val):
-        KERNEL_CALLS["symbolic"] += 1
-        return plain(offsets, bin_tsize, indptr_a, indptr_b,
-                     a_idx, a_val, b_idx, b_val)
-
-    @sym.def_vmap
-    def _rule(axis_size, in_batched, *args):
-        KERNEL_CALLS["batched_symbolic"] += 1
-        args = [x if bd else jnp.broadcast_to(x, (axis_size,) + x.shape)
-                for x, bd in zip(args, in_batched)]
-        out = K.batched_symbolic_call(axis_size, n_bins, m, cap_a, cap_b,
-                                      table_size, vector, interpret)(*args)
-        return out, True
-
-    return sym
+def _pad_flat(x: jax.Array, n: int) -> jax.Array:
+    """Pad the last axis to ``n`` with zeros and flatten."""
+    pad = [(0, 0)] * (x.ndim - 1) + [(0, n - x.shape[-1])]
+    return jnp.pad(x, pad).reshape(-1)
 
 
 @functools.lru_cache(maxsize=256)
-def _numeric_entry(n_bins: int, m: int, cap_a: int, cap_b: int, cap_c: int,
-                   table_size: int, vector: bool, interpret: bool):
-    plain = K.numeric_call(n_bins, m, cap_a, cap_b, cap_c, table_size,
-                           vector, interpret)
+def _entry(numeric: bool, n_bins: int, m: int, cap_a: int, cap_b: int,
+           cap_c: int, table_size: int, vector: bool, interpret: bool):
+    lens = (padded_len(m + 1, K.WIN_A), padded_len(cap_a, K.WIN_A),
+            padded_len(cap_b, K.WIN_B))
+    n_out = cap_c if numeric else m
+    out_len = K.stream_len(n_out)
+    # operand groups: schedule, indptr_a, A entries, B entries
+    groups = ((0, 1), (2,), (3, 4, 5), (6, 7)) if numeric else \
+        ((0, 1), (2,), (3, 4), (5,))
+    name = "numeric" if numeric else "symbolic"
+
+    def run(n_members, in_batched, args):
+        args, strides = list(args), []
+        for gi, grp in enumerate(groups):
+            batched = any(in_batched[k] for k in grp)
+            for k in grp:
+                x = args[k]
+                if batched and not in_batched[k]:
+                    x = jnp.broadcast_to(x, (n_members,) + x.shape)
+                args[k] = x.reshape(-1) if gi == 0 else \
+                    _pad_flat(x, lens[gi - 1])
+            strides.append((1 if gi == 0 else lens[gi - 1])
+                           if batched else 0)
+        build = K.numeric_call if numeric else K.symbolic_call
+        call = build(n_members, n_bins, m, lens, out_len,
+                     tuple(strides) + (out_len,), table_size, vector,
+                     interpret)
+        return [o.reshape(n_members, out_len)[:, :n_out]
+                for o in call(*args)]
 
     @custom_batching.custom_vmap
-    def num(offsets, bin_tsize, indptr_a, indptr_b, indptr_c,
-            a_idx, a_val, b_idx, b_val):
-        KERNEL_CALLS["numeric"] += 1
-        cols, vals = plain(offsets, bin_tsize, indptr_a, indptr_b, indptr_c,
-                           a_idx, a_val, b_idx, b_val)
-        return cols, vals
+    def entry(*args):
+        KERNEL_CALLS[name] += 1
+        outs = [o[0] for o in run(1, [False] * len(args), args)]
+        return tuple(outs) if numeric else outs[0]
 
-    @num.def_vmap
+    @entry.def_vmap
     def _rule(axis_size, in_batched, *args):
-        KERNEL_CALLS["batched_numeric"] += 1
-        args = [x if bd else jnp.broadcast_to(x, (axis_size,) + x.shape)
-                for x, bd in zip(args, in_batched)]
-        cols, vals = K.batched_numeric_call(
-            axis_size, n_bins, m, cap_a, cap_b, cap_c, table_size, vector,
-            interpret)(*args)
-        return (cols, vals), (True, True)
+        KERNEL_CALLS["batched_" + name] += 1
+        outs = run(axis_size, in_batched, args)
+        if numeric:
+            return tuple(outs), (True, True)
+        return outs[0], True
 
-    return num
+    return entry
+
+
+def _a_entries(a: CSR, b: CSR):
+    """Per A entry: start and length of the B row it selects."""
+    bs = b.indptr[a.indices].astype(jnp.int32)
+    bl = b.indptr[a.indices + 1].astype(jnp.int32) - bs
+    return bs, bl
+
+
+def _resolve_schedule(a: CSR, b: CSR, n_bins: int, table_size, schedule):
+    if schedule is None:
+        return hash_schedule(a, b, n_bins, table_size)
+    offsets, bin_tsize = schedule
+    assert table_size is not None, \
+        "a precomputed schedule needs its static table_size"
+    return offsets, bin_tsize, max(table_size, K.CHUNK)
 
 
 def spgemm_hash(a: CSR, b: CSR, cap_c: int, *, n_bins: int = 8,
@@ -191,32 +213,25 @@ def spgemm_hash(a: CSR, b: CSR, cap_c: int, *, n_bins: int = 8,
         from repro.core.spgemm import spgemm_hash_jnp
         return spgemm_hash_jnp(a, b, cap_c, semiring=semiring, mask=mask,
                                complement_mask=complement_mask)
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = _compat.resolve_interpret(interpret)
     m, n = a.n_rows, b.n_cols
-    if schedule is None:
-        offsets, bin_tsize, table_size = hash_schedule(a, b, n_bins,
-                                                       table_size)
-    else:
-        offsets, bin_tsize = schedule
-        assert table_size is not None, \
-            "a precomputed schedule needs its static table_size"
-        table_size = max(table_size, K.CHUNK)
+    offsets, bin_tsize, table_size = _resolve_schedule(a, b, n_bins,
+                                                       table_size, schedule)
     n_bins = offsets.shape[0] - 1
+    bs, bl = _a_entries(a, b)
+    ip_a = a.indptr.astype(jnp.int32)
 
     if indptr_c is None:
-        sym = _symbolic_entry(n_bins, m, a.cap, b.cap, table_size, vector,
-                              interpret)
-        row_nnz = sym(offsets, bin_tsize, a.indptr, b.indptr,
-                      a.indices, a.data.astype(jnp.float32),
-                      b.indices, b.data.astype(jnp.float32))
+        sym = _entry(False, n_bins, m, a.cap, b.cap, 0, table_size, vector,
+                     interpret)
+        row_nnz = sym(offsets, bin_tsize, ip_a, bs, bl, b.indices)
         indptr_c = sched.prefix_sum(row_nnz).astype(jnp.int32)
 
-    num = _numeric_entry(n_bins, m, a.cap, b.cap, cap_c, table_size, vector,
-                         interpret)
-    cols_c, vals_c = num(offsets, bin_tsize, a.indptr, b.indptr, indptr_c,
-                         a.indices, a.data.astype(jnp.float32),
-                         b.indices, b.data.astype(jnp.float32))
+    num = _entry(True, n_bins, m, a.cap, b.cap, cap_c, table_size, vector,
+                 interpret)
+    cols_c, vals_c = num(offsets, bin_tsize, ip_a, bs, bl,
+                         a.data.astype(jnp.float32), b.indices,
+                         b.data.astype(jnp.float32))
     nnz_c = indptr_c[-1]
     valid = jnp.arange(cap_c, dtype=jnp.int32) < nnz_c
     cols_c = jnp.where(valid, cols_c, 0)
@@ -229,20 +244,12 @@ def spgemm_hash_symbolic(a: CSR, b: CSR, *, n_bins: int = 8,
                          interpret: bool | None = None,
                          schedule=None) -> jax.Array:
     """Symbolic phase only: exact nnz(C) per row."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    m = a.n_rows
-    if schedule is None:
-        offsets, bin_tsize, table_size = hash_schedule(a, b, n_bins,
-                                                       table_size)
-    else:
-        offsets, bin_tsize = schedule
-        assert table_size is not None, \
-            "a precomputed schedule needs its static table_size"
-        table_size = max(table_size, K.CHUNK)
+    interpret = _compat.resolve_interpret(interpret)
+    offsets, bin_tsize, table_size = _resolve_schedule(a, b, n_bins,
+                                                       table_size, schedule)
     n_bins = offsets.shape[0] - 1
-    sym = _symbolic_entry(n_bins, m, a.cap, b.cap, table_size, vector,
-                          interpret)
-    return sym(offsets, bin_tsize, a.indptr, b.indptr,
-               a.indices, a.data.astype(jnp.float32),
-               b.indices, b.data.astype(jnp.float32))
+    bs, bl = _a_entries(a, b)
+    sym = _entry(False, n_bins, a.n_rows, a.cap, b.cap, 0, table_size,
+                 vector, interpret)
+    return sym(offsets, bin_tsize, a.indptr.astype(jnp.int32), bs, bl,
+               b.indices)

@@ -49,11 +49,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import _compat
-
 
 def _full(spec_len):
-    # whole-array block shared by every grid program (see spgemm_hash)
+    # whole-array block shared by every grid program
     return pl.BlockSpec((spec_len,), lambda g, *prefetch: (0,))
 
 
@@ -100,7 +98,7 @@ def scatter_call(n_buckets: int, bucket_cap: int, cap_a: int, cap_b: int,
         kernel, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((n_buckets, bucket_cap), jnp.float32),
         interpret=interpret,
-        compiler_params=_compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
     ))
 
@@ -143,7 +141,7 @@ def merge_call(n_buckets: int, bucket_cap: int, cap_c: int, interpret: bool):
         kernel, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((cap_c,), jnp.float32),
         interpret=interpret,
-        compiler_params=_compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
     ))
 
@@ -194,7 +192,7 @@ def batched_scatter_call(n_members: int, n_buckets: int, bucket_cap: int,
         out_shape=jax.ShapeDtypeStruct((n_members, n_buckets, bucket_cap),
                                        jnp.float32),
         interpret=interpret,
-        compiler_params=_compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
     ))
 
@@ -232,6 +230,6 @@ def batched_merge_call(n_members: int, n_buckets: int, bucket_cap: int,
         kernel, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((n_members, cap_c), jnp.float32),
         interpret=interpret,
-        compiler_params=_compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
     ))

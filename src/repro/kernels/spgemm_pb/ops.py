@@ -17,12 +17,12 @@ from __future__ import annotations
 
 import functools
 
-import jax
 import jax.numpy as jnp
 from jax import custom_batching
 
 from repro.core.formats import CSR
 
+from repro.kernels import _compat
 from . import kernel as K
 
 KERNEL_CALLS = {
@@ -41,10 +41,6 @@ def reset_kernel_calls() -> None:
 
 def kernel_call_counts() -> dict:
     return dict(KERNEL_CALLS)
-
-
-def _default_interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 # ---------------------------------------------------------------------------
@@ -106,8 +102,7 @@ def pb_scatter(a_data, b_data, src_a, src_b, bucket_nnz, *,
     Returns ``pp`` of shape ``(n_buckets, bucket_cap)`` (float32), pad
     lanes zeroed.  All index arrays come frozen out of a ``PBPlan``.
     """
-    if interpret is None:
-        interpret = _default_interpret()
+    interpret = _compat.resolve_interpret(interpret)
     n_buckets, bucket_cap = src_a.shape
     entry = _scatter_entry(n_buckets, bucket_cap, a_data.shape[0],
                            b_data.shape[0], interpret)
@@ -123,8 +118,7 @@ def pb_merge(pp, seg, bucket_nnz, cap_c: int, *,
     bucket independently: the plan guarantees buckets never share an
     output slot (one column segment per bucket).
     """
-    if interpret is None:
-        interpret = _default_interpret()
+    interpret = _compat.resolve_interpret(interpret)
     n_buckets, bucket_cap = pp.shape
     entry = _merge_entry(n_buckets, bucket_cap, cap_c, interpret)
     return entry(bucket_nnz, seg, pp)

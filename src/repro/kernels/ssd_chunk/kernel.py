@@ -27,7 +27,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import _compat
 
 
 def _ssd_kernel(xd_ref, la_ref, b_ref, c_ref, y_ref, hT_ref, state_scr, *,
@@ -93,6 +92,6 @@ def ssd_call(batch: int, seq: int, nh: int, hp: int, g: int, n: int,
                    jax.ShapeDtypeStruct((batch, nh, n, hp), jnp.float32)],
         scratch_shapes=[pltpu.VMEM((n, hp), jnp.float32)],
         interpret=interpret,
-        compiler_params=_compat.CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
     ))

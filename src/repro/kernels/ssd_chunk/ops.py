@@ -1,8 +1,7 @@
 """Jit'd wrapper for the SSD chunk kernel."""
 from __future__ import annotations
 
-import jax
-
+from repro.kernels import _compat
 from . import kernel as K
 
 
@@ -15,8 +14,7 @@ def ssd_pallas(xd, log_a, Bm, Cm, chunk: int, *,
     -- note hT is (n, hp)-ordered; transpose to match SSMCache.h's
     (hp, n) if feeding the decode path.
     """
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = _compat.resolve_interpret(interpret)
     b, s, nh, hp = xd.shape
     g, n = Bm.shape[2], Bm.shape[3]
     call = K.ssd_call(b, s, nh, hp, g, n, min(chunk, s), xd.dtype,

@@ -25,7 +25,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from repro.parallel.sharding import ParallelCtx, safe_pspec
 from . import layers as L
@@ -191,7 +191,7 @@ def apply_ep(params, x, cfg, pctx: ParallelCtx):
     fn = shard_map(local, mesh=mesh,
                    in_specs=(x_spec, r_spec, wg_spec, wg_spec, wo_spec),
                    out_specs=(out_spec, P()),
-                   check_rep=False)
+                   check_vma=False)
     return fn(x, params["router"], params["we_gate"], params["we_in"],
               params["we_out"])
 

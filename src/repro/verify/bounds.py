@@ -129,12 +129,11 @@ def _check_spgemm_vcs(plan) -> List[VC]:
     flop = np.asarray(plan.flop).astype(np.int64)[:m]
 
     # i32 admissibility, recomputed in exact Python ints the way
-    # schedule.guard_i32_flop admits it (bin targets scale by n_bins-1)
+    # schedule.guard_i32_flop admits it
     total = int(flop.sum())
-    scaled = total * max(plan.n_bins - 1, 1)
     vcs.append(_vc("i32-flop", total == int(plan.total_flop)
-                   and scaled <= _I32_MAX,
-                   f"total_flop={total}, x(n_bins-1)={scaled} <= 2^31-1"))
+                   and total <= _I32_MAX,
+                   f"total_flop={total} <= 2^31-1"))
     vcs.append(_vc("expansion-capacity", int(plan.flop_cap) >= total,
                    f"flop_cap={plan.flop_cap} >= total_flop={total}"))
 
@@ -171,11 +170,9 @@ def _check_bcsr_vcs(plan) -> List[VC]:
                    f"{plan.block_b[0]}"))
 
     total = int(flop.sum())
-    scaled = total * max(plan.n_bins - 1, 1)
     vcs.append(_vc("i32-flop", total == int(plan.total_flop)
-                   and scaled <= _I32_MAX,
-                   f"block total_flop={total}, x(n_bins-1)={scaled} "
-                   "<= 2^31-1"))
+                   and total <= _I32_MAX,
+                   f"block total_flop={total} <= 2^31-1"))
     vcs.append(_vc("nnz-consistent",
                    int(np.asarray(plan.indptr_cb)[-1]) == int(plan.nnzb_c)
                    and int(plan.nnzb_c) <= int(plan.bcap_c),

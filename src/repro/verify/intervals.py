@@ -45,6 +45,7 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 import numpy as np
 
 import jax
+import jax.extend.core
 
 _INF = math.inf
 _I32_MAX = 2**31 - 1
@@ -252,7 +253,8 @@ class JaxprAnalyzer:
             self.counts[prim] += 1
         invals = [self._read(env, v) for v in eqn.invars]
 
-        if prim in ("pjit", "closed_call", "core_call", "custom_vmap_call",
+        if prim in ("pjit", "jit", "closed_call", "core_call",
+                    "custom_vmap_call",
                     "custom_jvp_call", "custom_vjp_call",
                     "custom_vjp_call_jaxpr", "remat", "checkpoint",
                     "shard_map"):
@@ -357,6 +359,11 @@ class JaxprAnalyzer:
             if b.lo > 0 and a.lo >= 0 and a.hi != _INF:
                 return Ival(a.lo // b.hi if b.hi != _INF else 0,
                             a.hi // b.lo)
+            return TOP
+        if prim in ("shift_right_arithmetic", "shift_right_logical"):
+            if a.lo >= 0 and b.lo >= 0 and b.hi != _INF:
+                hi = a.hi if a.hi == _INF else int(a.hi) >> int(b.lo)
+                return Ival(int(a.lo) >> int(b.hi), hi)
             return TOP
         if prim == "iota":
             n = eqn.params["shape"][eqn.params["dimension"]]
@@ -599,7 +606,7 @@ class JaxprAnalyzer:
             if cj is not None and hasattr(cj, "eqns") \
                     and hasattr(cj, "invars") and not getattr(
                         cj, "constvars", True):
-                return jax.core.ClosedJaxpr(cj, [])
+                return jax.extend.core.ClosedJaxpr(cj, [])
         for v in eqn.params.values():
             if hasattr(v, "jaxpr") and hasattr(v.jaxpr, "eqns"):
                 return v
@@ -645,6 +652,11 @@ class JaxprAnalyzer:
         narrowed = list(carries)
         pos = {v: i for i, v in enumerate(jaxpr.invars)}
         n_consts = len(cond_consts)
+
+        def carry_index(atom) -> int:       # literals are never carries
+            return -1 if hasattr(atom, "val") else pos.get(atom, -1) - \
+                n_consts
+
         for eqn in jaxpr.eqns:
             name = eqn.primitive.name
             if name in ("lt", "le", "gt", "ge") and len(eqn.invars) == 2:
@@ -654,12 +666,12 @@ class JaxprAnalyzer:
                     a, b, av, bv = b, a, bv, av
                     name = "lt" if name == "gt" else "le"
                 ub = bv.hi - (1 if name == "lt" else 0)
-                i = pos.get(a, -1) - n_consts
+                i = carry_index(a)
                 if 0 <= i < len(narrowed) and ub != _INF:
                     c = narrowed[i]
                     narrowed[i] = Ival(c.lo, min(c.hi, ub))
                 lb = av.lo + (1 if name == "lt" else 0)
-                j = pos.get(b, -1) - n_consts
+                j = carry_index(b)
                 if 0 <= j < len(narrowed) and lb != -_INF:
                     c = narrowed[j]
                     narrowed[j] = Ival(max(c.lo, lb), c.hi)

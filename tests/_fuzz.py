@@ -107,7 +107,6 @@ def run_planned_hash_in_context(a: CSR, b: CSR, member_vals: np.ndarray,
     import dataclasses
     import jax
     from jax.sharding import Mesh, PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
     from repro.core import plan_spgemm
     from repro.kernels.spgemm_hash import ops as hash_ops
 
@@ -135,10 +134,10 @@ def run_planned_hash_in_context(a: CSR, b: CSR, member_vals: np.ndarray,
             body = lambda v: jax.vmap(one)(v)
         else:
             raise ValueError(f"unknown trace context {context!r}")
-        # check_rep=False matches the production executors in
+        # check_vma=False matches the production executors in
         # core.distributed: custom_vmap_call has no replication rule
-        dense = shard_map(body, mesh=mesh, in_specs=(P(),),
-                          out_specs=P(), check_rep=False)(vals)
+        dense = jax.shard_map(body, mesh=mesh, in_specs=(P(),),
+                              out_specs=P(), check_vma=False)(vals)
     counts = {k: v - before[k]
               for k, v in hash_ops.kernel_call_counts().items()}
     return np.asarray(dense), counts

@@ -42,7 +42,12 @@ sp = pytest.importorskip("scipy.sparse")
 
 
 def _bsr(d: np.ndarray, block):
-    return sp.bsr_matrix(np.asarray(d, np.float32), blocksize=block)
+    # scipy's dense -> BSR conversion may leave a block row's columns
+    # unsorted (scipy 1.17, when the block row's first nonzero lies in a
+    # later tile than another of its tiles); the oracle compares sorted
+    m = sp.bsr_matrix(np.asarray(d, np.float32), blocksize=block)
+    m.sort_indices()
+    return m
 
 
 def _assert_bcsr_matches_scipy(ours: BCSR, oracle) -> None:

@@ -14,9 +14,10 @@ Covered for the Pallas kernels (``spgemm_hash``, scalar and vectorized
 probing -- at table size == CHUNK the vector path degenerates to a single
 chunk, its own edge) and the jnp fallback (``spgemm_hash_jnp``), sorted
 and unsorted output, plus the planner path that freezes per-bin sizes --
-and, under ``jax.vmap`` over a two-member value fleet, the batched-grid
-twins of both kernels at the same boundaries (the saturated table is
-per-program scratch: members must not observe each other's slots).
+and, under ``jax.vmap``, the batched grid at the same boundaries: the
+numeric kernel over a two-member value fleet, the symbolic kernel over
+a two-member structure fleet (the saturated table is per-program
+scratch: members must not observe each other's slots).
 
 Values are dyadic so every comparison is exact (bitwise on the dense
 view).
@@ -115,8 +116,9 @@ def _vmap_saturation_fleet(a, b, d, vector, table_size, schedule):
     ``jax.vmap`` and return per-member ``(indptr, dense)`` stacks plus the
     kernel-counter delta.  The schedule override closes over the vmapped
     call, so the ``custom_vmap`` rule must broadcast it onto the batched
-    grid; the unplanned entry also exercises the batched *symbolic*
-    kernel counting a saturated table."""
+    grid.  The fleet shares its structure, so the symbolic kernel runs
+    once, unbatched; :func:`test_batched_symbolic_load_factor_one_under_vmap`
+    batches the structure instead."""
     import dataclasses
 
     import jax
@@ -147,7 +149,7 @@ def test_batched_grid_load_factor_one_under_vmap(vector):
     bin_tsize = jnp.asarray([d], jnp.int32)
     member_vals, ips, denses, counts = _vmap_saturation_fleet(
         a, b, d, vector, table_size=d, schedule=(offsets, bin_tsize))
-    assert counts["batched_symbolic"] > 0 and counts["batched_numeric"] > 0
+    assert counts["symbolic"] > 0 and counts["batched_numeric"] > 0
     for e in range(2):
         assert ips[e, 1] - ips[e, 0] == d and ips[e, 2] - ips[e, 1] == d
         a_e = CSR(a.indptr, a.indices, member_vals[e], a.nnz, a.shape,
@@ -168,12 +170,58 @@ def test_batched_grid_one_past_fill_doubles_table_under_vmap(vector):
     member_vals, ips, denses, counts = _vmap_saturation_fleet(
         a, b, d, vector, table_size=table_size,
         schedule=(offsets, bin_tsize))
-    assert counts["batched_symbolic"] > 0 and counts["batched_numeric"] > 0
+    assert counts["symbolic"] > 0 and counts["batched_numeric"] > 0
     for e in range(2):
         assert ips[e, 1] - ips[e, 0] == d and ips[e, 2] - ips[e, 1] == d
         a_e = CSR(a.indptr, a.indices, member_vals[e], a.nnz, a.shape,
                   sorted_cols=a.sorted_cols)
         assert np.array_equal(denses[e].astype(np.float64),
+                              _oracle(a_e, b)), e
+
+
+@pytest.mark.parametrize("vector", (False, True))
+def test_batched_symbolic_load_factor_one_under_vmap(vector):
+    """A fleet of two A *structures* (index sets) under ``vmap`` with no
+    ``indptr_c``: the batched symbolic kernel counts every member's rows
+    at a pinned load-factor-1.0 table, per-member strides keep the
+    members apart, and the trailing row left out of the schedule is
+    counted zero by the kernel's closing pad.
+
+    B rows 0 and 1 hold the two halves of ``d`` columns, B row 2 all of
+    them.  Member 0 takes A rows ``[2] [0 1] []``, member 1 ``[0] [2 0] []``
+    -- row counts ``(d, d, 0)`` and ``(d/2, d, 0)``; each row that reaches
+    ``d`` fills the table.
+    """
+    import dataclasses
+
+    import jax
+
+    d = CHUNK
+    h = d // 2
+    rows = np.concatenate([np.zeros(h), np.ones(h), np.full(d, 2)])
+    cols = np.concatenate([np.arange(h), np.arange(h, d), np.arange(d)])
+    vals = VALS[np.arange(2 * d) % len(VALS)]
+    b = CSR.from_numpy_coo(rows.astype(np.int64), cols, vals, (3, d))
+    a = CSR.from_numpy_coo([0, 1, 1], [2, 0, 1], [1.0, 1.0, 0.5], (3, 3))
+    idx = jnp.stack([a.indices, a.indices.at[:3].set(jnp.asarray([0, 2, 0]))])
+    schedule = (jnp.asarray([0, 2], jnp.int32),     # row 2: past the bin
+                jnp.asarray([d], jnp.int32))
+
+    def one(ix):
+        c = hash_ops.spgemm_hash(dataclasses.replace(a, indices=ix), b,
+                                 cap_c=2 * d, vector=vector, table_size=d,
+                                 schedule=schedule)
+        return c.indptr, c.to_dense()
+
+    hash_ops.reset_kernel_calls()
+    ips, denses = jax.vmap(one)(idx)
+    counts = hash_ops.kernel_call_counts()
+    assert counts["batched_symbolic"] > 0
+    assert np.array_equal(np.diff(np.asarray(ips), axis=1),
+                          [[d, d, 0], [h, d, 0]])
+    for e in range(2):
+        a_e = dataclasses.replace(a, indices=idx[e])
+        assert np.array_equal(np.asarray(denses[e], np.float64),
                               _oracle(a_e, b)), e
 
 

@@ -19,7 +19,8 @@ from repro.kernels.flash_attention.ref import attention_ref
 
 @pytest.mark.parametrize("vector", [False, True])
 @pytest.mark.parametrize("scale,ef,preset", [
-    (4, 2, "ER"), (5, 3, "G500"), (5, 4, "ER"), (6, 2, "G500")])
+    (4, 2, "ER"), (5, 3, "G500"), (5, 4, "ER"), (6, 2, "G500"),
+    (7, 8, "G500")])     # 128 columns: a two-row (256-slot) table
 def test_hash_spgemm_sweep(vector, scale, ef, preset):
     a = rmat_csr(scale, ef, preset, seed=scale + ef)
     b = rmat_csr(scale, ef, "ER", seed=scale + ef + 1)
@@ -64,6 +65,25 @@ def test_hash_empty_matrix():
     z = CSR.from_dense(jnp.zeros((8, 8), jnp.float32), cap=4)
     c = spgemm_hash(z, z, cap_c=4, n_bins=2, table_size=8)
     assert int(c.nnz) == 0
+
+
+@pytest.mark.parametrize("vector", [False, True])
+def test_hash_undersized_cap_c_truncates(vector):
+    """``cap_c`` < nnz(C) keeps exactly the first ``cap_c`` entries of the
+    full product: the output stream runs past its one-window buffer and
+    no window past the buffer may be written."""
+    from repro.kernels.spgemm_hash.kernel import WIN_OUT
+    a = rmat_csr(7, 8, "G500", seed=3)
+    b = rmat_csr(7, 8, "ER", seed=4)
+    full = spgemm_hash(a, b, 128 * 128, vector=vector, n_bins=4)
+    nnz = int(full.nnz)
+    cap = 100
+    assert nnz > WIN_OUT >= cap
+    c = spgemm_hash(a, b, cap, vector=vector, n_bins=4)
+    assert np.array_equal(np.asarray(c.indptr), np.asarray(full.indptr))
+    assert np.array_equal(np.asarray(c.indices),
+                          np.asarray(full.indices)[:cap])
+    assert np.array_equal(np.asarray(c.data), np.asarray(full.data)[:cap])
 
 
 # ---------------- BCSR SpGEMM ------------------------------------------------
@@ -134,3 +154,40 @@ def test_chunked_attention_decode_shapes(sq, skv, rng):
     ref = attention_ref(q, k, v, causal=True)
     out = chunked_attention(q, k, v, causal=True, bkv=32)
     assert float(jnp.abs(out - ref).max()) < 2e-5
+
+
+# ---------------- where kernels run -----------------------------------------
+
+@pytest.mark.parametrize("backend,want", [("cpu", True), ("tpu", False),
+                                          ("gpu", None)])
+def test_resolve_interpret_by_backend(backend, want, monkeypatch):
+    """Interpret on the CPU, compile on a TPU, and refuse anything else
+    rather than silently interpreting; an explicit choice always wins."""
+    import jax
+    from repro.kernels import _compat
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    assert _compat.resolve_interpret(True) is True
+    assert _compat.resolve_interpret(False) is False
+    if want is None:
+        with pytest.raises(RuntimeError, match="gpu"):
+            _compat.resolve_interpret()
+    else:
+        assert _compat.resolve_interpret() is want
+
+
+@pytest.mark.parametrize("env", [None, "/elsewhere/cache"])
+def test_use_compile_cache_honours_env(env, monkeypatch, tmp_path):
+    import jax
+    from repro.kernels import _compat
+    if env is None:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    else:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", env)
+    prev = jax.config.jax_compilation_cache_dir
+    try:
+        got = _compat.use_compile_cache(str(tmp_path))
+        assert got == (env or str(tmp_path))
+        assert jax.config.jax_compilation_cache_dir == \
+            (prev if env else str(tmp_path))
+    finally:
+        jax.config.update("jax_compilation_cache_dir", prev)

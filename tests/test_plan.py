@@ -124,7 +124,7 @@ def test_rows_to_bins_overflow_guard():
     import jax
     huge = jnp.full((8,), 2**30, jnp.int32)   # total 2^33 >> int32
     if jax.config.jax_enable_x64:
-        sched.guard_i32_flop(huge, 8, "rows_to_bins")       # no raise
+        sched.guard_i32_flop(huge, "rows_to_bins")          # no raise
         off = np.asarray(sched.rows_to_bins(huge, 4))
         assert off[0] == 0 and off[-1] == 8
         # uniform rows: the equal-flop partition is exact under int64
@@ -135,11 +135,24 @@ def test_rows_to_bins_overflow_guard():
         with pytest.raises(OverflowError, match="overflows the int32"):
             sched.rows_to_bins(huge, 8)
         with pytest.raises(OverflowError):
-            sched.guard_i32_flop(huge, 1, "bin_flop")
+            sched.guard_i32_flop(huge, "bin_flop")
     # sane totals stay silent and exact in both modes
     ok = jnp.full((8,), 1000, jnp.int32)
     off = np.asarray(sched.rows_to_bins(ok, 4))
     assert off[0] == 0 and off[-1] == 8
+
+
+def test_rows_to_bins_exact_when_total_times_bins_passes_int32():
+    """A total under 2^31 bins exactly even where ``total * (n_bins - 1)``
+    would wrap int32 (G500 scale 16 has 400M flop, x7 targets = 2.8e9):
+    the result equals the int64 host partition."""
+    rng = np.random.default_rng(0)
+    flop = rng.integers(0, 2**16, size=4096).astype(np.int32)
+    flop[17] = 2**30                      # one heavy row; total < 2^31
+    assert flop.astype(np.int64).sum() < 2**31 <= \
+        flop.astype(np.int64).sum() * 7
+    off = np.asarray(sched.rows_to_bins(jnp.asarray(flop), 8))
+    assert np.array_equal(off, sched.equal_weight_partition(flop, 8))
 
 
 # ---------------------------------------------------------------------------
