@@ -37,6 +37,11 @@ under ``jit``, ``vmap`` (a batched grid over members via the kernels'
 ``custom_vmap`` rule), and inside ``shard_map`` bodies (DESIGN.md
 section 14).  ``spgemm_hash_jnp`` survives in the dispatch only as the
 reference oracle and as the body for general semirings.
+
+:func:`plan_spgemm` records three host spans (:mod:`repro.tracing`):
+``repro.plan`` around the whole call, cache lookup included, and inside
+it ``repro.plan.profile`` (flop profile, bins, table sizes) and
+``repro.plan.symbolic`` (ESC symbolic through the nnz(C) sync).
 """
 from __future__ import annotations
 
@@ -49,6 +54,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import tracing
 from .formats import CSR
 from .semiring import Semiring, resolve_semiring
 from . import schedule as sched
@@ -322,7 +328,31 @@ class SpGEMMPlan:
 
     __call__ = execute
 
+    def kernel_counts(self, a: CSR, b: CSR) -> dict[str, int]:
+        """Run this plan's numeric hash kernel once with its counters on
+        and return them (``kernels.spgemm_hash.kernel.COUNTERS``), summed
+        over members.  They depend on the structure only, so they count
+        every ``execute`` of this plan.  Raises ``ValueError`` for a plan
+        that does not run the kernel (not ``hash``/``hash_vector``
+        plus-times unmasked) and where ``total_flop`` reaches 2^31, past
+        the counters' int32."""
+        if (self.algorithm not in ("hash", "hash_vector")
+                or self.semiring != "plus_times" or self.mask is not None):
+            raise ValueError(f"plan {self.algorithm!r} ({self.semiring}, "
+                             f"masked={self.mask is not None}) does not run "
+                             "the hash kernel")
+        if self.total_flop >= 2 ** 31:
+            raise ValueError(f"total_flop {self.total_flop} would overflow "
+                             "the kernel's int32 counters")
+        self.check_structure(a, b)
+        from repro.kernels.spgemm_hash import ops as hash_ops
+        return hash_ops.numeric_counts(
+            a, b, self.cap_c, schedule=(self.offsets, self.bin_tsize),
+            table_size=self.table_size,
+            vector=(self.algorithm == "hash_vector"))
 
+
+@tracing.span("repro.plan")
 def plan_spgemm(a: CSR, b: CSR, *, algorithm: str = "auto",
                 semiring: str | Semiring = "plus_times",
                 mask: Optional[CSR] = None, complement_mask: bool = False,
@@ -392,26 +422,33 @@ def plan_spgemm(a: CSR, b: CSR, *, algorithm: str = "auto",
     mask = _canon_mask(mask)
     n = b.n_cols
 
-    # Fig. 6: flop profile + equal-flop bins.  The eager form is the same
-    # code path make_schedule jits, but here the inputs are concrete so
-    # the int32 overflow guard raises loudly instead of mis-binning.
-    flop, offsets, tsize = sched.make_schedule_eager(a, b, n_bins)
-    max_row_flop = int(jnp.max(flop)) if flop.size else 0
-    total_flop = int(jnp.sum(flop))
+    with tracing.span("repro.plan.profile"):
+        # Fig. 6: flop profile + equal-flop bins.  The eager form is the
+        # same code path make_schedule jits, but here the inputs are
+        # concrete so the int32 overflow guard raises loudly instead of
+        # mis-binning.
+        flop, offsets, tsize = sched.make_schedule_eager(a, b, n_bins)
+        max_row_flop = int(jnp.max(flop)) if flop.size else 0
+        total_flop = int(jnp.sum(flop))
 
-    # Fig. 7 lines 9-12: static scratch allocation = global-max p2 bound;
-    # per-bin effective sizes ride in the plan as prefetched scalars.
-    table_size = max(sched.lowest_p2(min(max_row_flop, n) + 1), HK.CHUNK)
-    bin_tsize = sched.bin_table_sizes(tsize, n, table_size, floor=HK.CHUNK)
+        # Fig. 7 lines 9-12: static scratch allocation = global-max p2
+        # bound; per-bin effective sizes ride in the plan as prefetched
+        # scalars.
+        table_size = max(sched.lowest_p2(min(max_row_flop, n) + 1),
+                         HK.CHUNK)
+        bin_tsize = sched.bin_table_sizes(tsize, n, table_size,
+                                          floor=HK.CHUNK)
 
     # Symbolic phase with the exact flop bound -- the worst-case
     # O(cap_a * min(cap_b, n)) default buffer is never allocated on replan.
     flop_cap = max(total_flop, 1)
     if bucket_caps:
         flop_cap = sched.lowest_p2(flop_cap)
-    row_nnz_c, indptr_c, _, _ = symbolic(
-        a, b, mask=mask, complement_mask=complement_mask, flop_cap=flop_cap)
-    nnz_c = int(jnp.sum(row_nnz_c))
+    with tracing.span("repro.plan.symbolic", flop_cap=flop_cap):
+        row_nnz_c, indptr_c, _, _ = symbolic(
+            a, b, mask=mask, complement_mask=complement_mask,
+            flop_cap=flop_cap)
+        nnz_c = int(jnp.sum(row_nnz_c))
     cap_c = max(nnz_c, 1)
     row_cap = max(int(jnp.max(row_nnz_c)), 1)
     k_width = max(int(jnp.max(a.row_nnz())), 1)

@@ -10,6 +10,10 @@ staging window that is DMA'd to HBM when full.
 1-D int32/f32 arrays in HBM are tiled by ``ALIGN`` words, so every DMA
 here starts at a multiple of ``ALIGN`` and moves a whole number of tiles;
 callers pad operands with :func:`padded_len`.
+
+Both take an optional ``counter = (counter_ref, slot)``: an SMEM int32
+vector whose word ``slot`` counts the DMAs they issue (window refills,
+stream windows written).  Without it they emit no counting code.
 """
 from __future__ import annotations
 
@@ -36,18 +40,29 @@ def _copy_all(pairs, sems, sem0):
         c.wait()
 
 
+def count(counter, n=1):
+    """Add ``n`` to word ``slot`` of ``counter = (counter_ref, slot)``;
+    nothing where ``counter`` is None."""
+    if counter is not None:
+        ref, slot = counter
+        ref[slot] = ref[slot] + n
+
+
 class Window:
     """A read window of one or more same-indexed HBM arrays in SMEM.
 
     ``base_ref[slot]`` (an SMEM scalar) holds the flat index of the
     window's first word; ``sems[sem0:sem0 + len(hbm)]`` are its DMA
-    semaphores; ``win`` is a power-of-two multiple of ``ALIGN``.
+    semaphores; ``win`` is a power-of-two multiple of ``ALIGN``;
+    ``counter`` counts refills (module docstring).
     """
 
-    def __init__(self, hbm, smem, base_ref, slot, sems, sem0, win):
+    def __init__(self, hbm, smem, base_ref, slot, sems, sem0, win,
+                 counter=None):
         self.hbm, self.smem, self.win = hbm, smem, win
         self.base_ref, self.slot = base_ref, slot
         self.sems, self.sem0 = sems, sem0
+        self.counter = counter
 
     def reset(self):
         """Mark the window empty (its next read loads)."""
@@ -68,6 +83,7 @@ class Window:
                        for h, s in zip(self.hbm, self.smem)],
                       self.sems, self.sem0)
             self.base_ref[self.slot] = start
+            count(self.counter)
 
         off = (g - self.base_ref[self.slot]) & jnp.int32(self.win - 1)
         return [s[off] for s in self.smem]
@@ -82,12 +98,15 @@ class Stream:
     the open window whole, so the HBM arrays must be padded to whole
     windows.  ``end`` (a multiple of ``win``) bounds the stream: windows
     that would start at or past it are never written, so a stream longer
-    than its HBM slice is truncated to the slice.
+    than its HBM slice is truncated to the slice.  ``counter`` counts the
+    windows written (module docstring).
     """
 
-    def __init__(self, hbm, stage, state_ref, sems, sem0, win, end):
+    def __init__(self, hbm, stage, state_ref, sems, sem0, win, end,
+                 counter=None):
         self.hbm, self.stage, self.state = hbm, stage, state_ref
         self.sems, self.sem0, self.win, self.end = sems, sem0, win, end
+        self.counter = counter
 
     def start(self, pos):
         """Open the stream at HBM position ``pos`` (a multiple of ``win``)."""
@@ -104,6 +123,7 @@ class Stream:
             _copy_all([(s, h.at[pl.ds(base, self.win)])
                        for h, s in zip(self.hbm, self.stage)],
                       self.sems, self.sem0)
+            count(self.counter)
 
     def push(self, *vals):
         """Append one value to each of the stream's arrays."""

@@ -43,6 +43,12 @@ Memory plumbing (what the TPU compiler admits):
     output of bin ``b + 1`` starts where bin ``b`` stopped (rows are
     contiguous), so one output stream per member runs across its bins.
 
+Counters (``counters=True``): each member keeps an SMEM int32 vector of
+the :data:`COUNTERS`, zeroed where its stream opens and DMA'd to one
+extra output where it closes.  They depend only on the sparsity pattern
+and the schedule, never on the values.  With ``counters=False`` the
+kernel has no counter scratch, output or op.
+
 The A side reaches the kernel pre-gathered per A entry: ``a_bs[j] =
 indptr_b[a_idx[j]]`` and ``a_bl[j]`` (that B row's length), so no random
 access into ``indptr_b`` happens in the kernel.
@@ -56,7 +62,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._smem import Stream, Window
+from repro.kernels._smem import ALIGN, Stream, Window, _copy_all, count
 
 #: Knuth multiplicative constant (wraps mod 2^32; int32 two's complement).
 HASH_CONST = -1640531527   # == 2654435761 mod 2^32 (Python int -> inlined literal)
@@ -74,6 +80,17 @@ WIN_B = 1024
 WIN_OUT = 2048
 
 EMPTY = -1
+
+#: The counter vector of a counted kernel, in order: probe-loop steps,
+#: table rows cleared per row of A (the numeric flush scans as many),
+#: refills of the indptr, A-entry and B-row windows, and output windows
+#: written to HBM.
+COUNTERS = ("probe_steps", "table_rows", "ip_loads", "a_loads", "b_loads",
+            "out_flushes")
+PROBE_STEPS, TABLE_ROWS, IP_LOADS, A_LOADS, B_LOADS, OUT_FLUSHES = \
+    range(len(COUNTERS))
+#: Words of one member's counter vector (SMEM and output): one HBM tile.
+CWIN = ALIGN
 
 
 def _hash(key: jax.Array, mask: jax.Array) -> jax.Array:
@@ -118,7 +135,8 @@ def _clear(tkey_ref, tval_ref, n_rows, rows):
 
 
 def _probe(tkey_ref, key, tsz, row_mask, vector):
-    """Find ``key`` or the slot it should take; returns ``(row, lane, new)``.
+    """Find ``key`` or the slot it should take; returns ``(row, lane, new,
+    steps)``, ``steps`` being the probe loop's iterations.
 
     ``tsz`` is the (traced, p2) effective table size; tables narrower than
     a row use the first ``tsz`` lanes of row 0.  ``row_mask`` is the static
@@ -152,17 +170,23 @@ def _probe(tkey_ref, key, tsz, row_mask, vector):
         nxt = jnp.where(found < LANES, r, (r + 1) & rmask)
         return nxt, it + 1, found, (s >= LANES).astype(jnp.int32)
 
-    r, _, found, new = jax.lax.while_loop(
+    r, steps, found, new = jax.lax.while_loop(
         cond, body, (r0, jnp.int32(0), jnp.int32(LANES), jnp.int32(0)))
-    return r & row_mask, found, new
+    return r & row_mask, found, new, steps
 
 
 def _hash_kernel(off_ref, tsz_ref, *refs, numeric, vector, n_bins, m,
-                 table_size, strides, lens):
+                 table_size, strides, lens, counters):
     n_in = 6 if numeric else 4
     n_out = 2 if numeric else 1
     ins, outs = refs[:n_in], refs[n_in:n_in + n_out]
     scratch = refs[n_in + n_out:]
+    if counters:
+        cnt_hbm, *scratch, cnt_ref = scratch
+
+    def ctr(slot):
+        return (cnt_ref, slot) if counters else None
+
     if numeric:
         ip_hbm, bs_hbm, bl_hbm, av_hbm, bi_hbm, bv_hbm = ins
         (ip_w, bs_w, bl_w, av_w, bi_w, bv_w, stage_k, stage_v, wbase,
@@ -185,13 +209,13 @@ def _hash_kernel(off_ref, tsz_ref, *refs, numeric, vector, n_bins, m,
     e = pl.program_id(0)
     b = pl.program_id(1)
     n_a, n_b = len(a_group[0]), len(b_group[0])
-    ipw = Window([ip_hbm], [ip_w], wbase, 0, sems, 0, WIN_A)
-    aw = Window(*a_group, wbase, 1, sems, 1, WIN_A)
-    bw = Window(*b_group, wbase, 2, sems, 1 + n_a, WIN_B)
+    ipw = Window([ip_hbm], [ip_w], wbase, 0, sems, 0, WIN_A, ctr(IP_LOADS))
+    aw = Window(*a_group, wbase, 1, sems, 1, WIN_A, ctr(A_LOADS))
+    bw = Window(*b_group, wbase, 2, sems, 1 + n_a, WIN_B, ctr(B_LOADS))
     # member e owns output words [e * s_out, (e + 1) * s_out): a numeric
     # stream past cap_c is truncated there, never written into member e + 1
     out = Stream(outs, stage, sstate, sems, 1 + n_a + n_b, WIN_OUT,
-                 end=(e + 1) * s_out)
+                 end=(e + 1) * s_out, counter=ctr(OUT_FLUSHES))
     for w in (ipw, aw, bw):
         w.reset()
     ip0, a0, b0 = e * s_ip, e * s_a, e * s_b
@@ -201,6 +225,9 @@ def _hash_kernel(off_ref, tsz_ref, *refs, numeric, vector, n_bins, m,
     @pl.when(b == 0)
     def _open():
         out.start(e * s_out)
+        if counters:
+            for k in range(len(COUNTERS)):
+                cnt_ref[k] = jnp.int32(0)
 
     es = e if s_sched else 0           # schedules: per member or shared
     lo = off_ref[es * (n_bins + 1) + b]
@@ -211,6 +238,7 @@ def _hash_kernel(off_ref, tsz_ref, *refs, numeric, vector, n_bins, m,
 
     def do_row(i, _):
         _clear(tkey_ref, tval_ref, n_rows, row_mask + 1)
+        count(ctr(TABLE_ROWS), n_rows)
         (p0,) = ipw.read(ip0 + i, ip_last)
         (p1,) = ipw.read(ip0 + i + 1, ip_last)
 
@@ -221,7 +249,9 @@ def _hash_kernel(off_ref, tsz_ref, *refs, numeric, vector, n_bins, m,
             def do_b(t, cnt):
                 bvals = bw.read(b0 + t, b_last)
                 key = bvals[0]
-                r, found, new = _probe(tkey_ref, key, tsz, row_mask, vector)
+                r, found, new, steps = _probe(tkey_ref, key, tsz, row_mask,
+                                              vector)
+                count(ctr(PROBE_STEPS), steps)
                 sel = lane == found
                 row = tkey_ref[pl.ds(r, 1), :]
                 tkey_ref[pl.ds(r, 1), :] = jnp.where(sel, key, row)
@@ -273,12 +303,15 @@ def _hash_kernel(off_ref, tsz_ref, *refs, numeric, vector, n_bins, m,
                 return c
             jax.lax.fori_loop(hi, m, pad, 0)
         out.flush()
+        if counters:
+            _copy_all([(cnt_ref, cnt_hbm.at[pl.ds(
+                pl.multiple_of(e * CWIN, CWIN), CWIN)])], sems, 0)
 
 
 @functools.lru_cache(maxsize=256)
 def _hash_call(numeric: bool, n_members: int, n_bins: int, m: int,
               lens: tuple, out_len: int, strides: tuple, table_size: int,
-              vector: bool, interpret: bool):
+              vector: bool, interpret: bool, counters: bool = False):
     """Cached builder: one jitted ``pallas_call`` per static config.
 
     ``lens = (l_ip, l_a, l_b)`` are the padded per-member lengths of the
@@ -291,11 +324,14 @@ def _hash_call(numeric: bool, n_members: int, n_bins: int, m: int,
     Operands (all flat, 1-D): ``offsets`` and ``bin_tsize`` (scalar
     prefetch), then ``indptr_a, a_bs, a_bl[, a_val], b_idx[, b_val]`` in
     HBM.  Returns ``row_nnz`` (symbolic) or ``(cols, vals)`` (numeric),
-    each ``n_members * out_len`` long.
+    each ``n_members * out_len`` long; ``counters=True`` appends the
+    ``n_members * CWIN`` counter output (member ``e``'s :data:`COUNTERS`
+    at ``e * CWIN``).
     """
     kernel = functools.partial(
         _hash_kernel, numeric=numeric, vector=vector, n_bins=n_bins, m=m,
-        table_size=table_size, strides=strides, lens=lens)
+        table_size=table_size, strides=strides, lens=lens,
+        counters=counters)
     smem = pltpu.SMEM
     n_a = 3 if numeric else 2
     n_b = 2 if numeric else 1
@@ -312,21 +348,26 @@ def _hash_call(numeric: bool, n_members: int, n_bins: int, m: int,
                   pltpu.VMEM((rows, LANES), jnp.int32)]
                + ([pltpu.VMEM((rows, LANES), jnp.float32)] if numeric
                   else [])
-               + [pltpu.SemaphoreType.DMA((1 + n_a + n_b + n_out,))])
+               + [pltpu.SemaphoreType.DMA((1 + n_a + n_b + n_out,))]
+               + ([smem((CWIN,), jnp.int32)] if counters else []))
     any_spec = pl.BlockSpec(memory_space=pl.ANY)
     n_in = 1 + n_a + n_b
     dtypes = [jnp.int32, jnp.float32][:n_out]
+    out_shape = [jax.ShapeDtypeStruct((n_members * out_len,), dt)
+                 for dt in dtypes]
+    if counters:
+        out_shape.append(jax.ShapeDtypeStruct((n_members * CWIN,),
+                                              jnp.int32))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,           # offsets, bin_tsize
         grid=(n_members, n_bins),
         in_specs=[any_spec] * n_in,
-        out_specs=[any_spec] * n_out,
+        out_specs=[any_spec] * len(out_shape),
         scratch_shapes=scratch,
     )
     call = pl.pallas_call(
         kernel, grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct((n_members * out_len,), dt)
-                   for dt in dtypes],
+        out_shape=out_shape,
         interpret=interpret,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
@@ -337,16 +378,16 @@ def _hash_call(numeric: bool, n_members: int, n_bins: int, m: int,
 
 def symbolic_call(n_members: int, n_bins: int, m: int, lens: tuple,
                   out_len: int, strides: tuple, table_size: int,
-                  vector: bool, interpret: bool):
+                  vector: bool, interpret: bool, counters: bool = False):
     """Symbolic phase (exact nnz per row of C); see :func:`_hash_call`."""
     return _hash_call(False, n_members, n_bins, m, lens, out_len, strides,
-                      table_size, vector, interpret)
+                      table_size, vector, interpret, counters)
 
 
 def numeric_call(n_members: int, n_bins: int, m: int, lens: tuple,
                  out_len: int, strides: tuple, table_size: int,
-                 vector: bool, interpret: bool):
+                 vector: bool, interpret: bool, counters: bool = False):
     """Numeric phase (unsorted columns and values); see
     :func:`_hash_call`."""
     return _hash_call(True, n_members, n_bins, m, lens, out_len, strides,
-                      table_size, vector, interpret)
+                      table_size, vector, interpret, counters)

@@ -45,7 +45,8 @@ dyadic fuzz values), and to 1 ulp per accumulated product otherwise.
 
 ``KERNEL_CALLS`` counts, at trace time, which Pallas entry was
 staged -- tests use it to prove the real kernel (not the jnp twin) is in a
-compiled program.
+compiled program.  :func:`numeric_counts` runs the numeric kernel once
+with its run-time counters on (``kernel.COUNTERS``).
 """
 from __future__ import annotations
 
@@ -53,6 +54,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import custom_batching
 
 from repro.core.formats import CSR
@@ -129,7 +131,8 @@ def _pad_flat(x: jax.Array, n: int) -> jax.Array:
 
 @functools.lru_cache(maxsize=256)
 def _entry(numeric: bool, n_bins: int, m: int, cap_a: int, cap_b: int,
-           cap_c: int, table_size: int, vector: bool, interpret: bool):
+           cap_c: int, table_size: int, vector: bool, interpret: bool,
+           counters: bool = False):
     lens = (padded_len(m + 1, K.WIN_A), padded_len(cap_a, K.WIN_A),
             padded_len(cap_b, K.WIN_B))
     n_out = cap_c if numeric else m
@@ -154,22 +157,27 @@ def _entry(numeric: bool, n_bins: int, m: int, cap_a: int, cap_b: int,
         build = K.numeric_call if numeric else K.symbolic_call
         call = build(n_members, n_bins, m, lens, out_len,
                      tuple(strides) + (out_len,), table_size, vector,
-                     interpret)
-        return [o.reshape(n_members, out_len)[:, :n_out]
-                for o in call(*args)]
+                     interpret, counters)
+        outs = call(*args)
+        if counters:
+            *outs, cnt = outs
+        outs = [o.reshape(n_members, out_len)[:, :n_out] for o in outs]
+        if counters:
+            outs.append(cnt.reshape(n_members, K.CWIN)[:, :len(K.COUNTERS)])
+        return outs
 
     @custom_batching.custom_vmap
     def entry(*args):
         KERNEL_CALLS[name] += 1
         outs = [o[0] for o in run(1, [False] * len(args), args)]
-        return tuple(outs) if numeric else outs[0]
+        return tuple(outs) if len(outs) > 1 else outs[0]
 
     @entry.def_vmap
     def _rule(axis_size, in_batched, *args):
         KERNEL_CALLS["batched_" + name] += 1
         outs = run(axis_size, in_batched, args)
-        if numeric:
-            return tuple(outs), (True, True)
+        if len(outs) > 1:
+            return tuple(outs), (True,) * len(outs)
         return outs[0], True
 
     return entry
@@ -253,3 +261,36 @@ def spgemm_hash_symbolic(a: CSR, b: CSR, *, n_bins: int = 8,
                  vector, interpret)
     return sym(offsets, bin_tsize, a.indptr.astype(jnp.int32), bs, bl,
                b.indices)
+
+
+def _counted(numeric: bool, a: CSR, b: CSR, cap_c: int, schedule,
+             table_size: int, vector: bool, interpret: bool | None):
+    """One run of the planned kernel with counters on: its raw outputs
+    (``row_nnz``, or ``cols, vals`` of length ``cap_c``) and its
+    ``kernel.COUNTERS`` as host ints, summed over members."""
+    interpret = _compat.resolve_interpret(interpret)
+    offsets, bin_tsize = schedule
+    bs, bl = _a_entries(a, b)
+    args = [offsets, bin_tsize, a.indptr.astype(jnp.int32), bs, bl]
+    if numeric:
+        args.append(a.data.astype(jnp.float32))
+    args.append(b.indices)
+    if numeric:
+        args.append(b.data.astype(jnp.float32))
+    call = _entry(numeric, offsets.shape[0] - 1, a.n_rows, a.cap, b.cap,
+                  cap_c if numeric else 0, max(table_size, K.CHUNK), vector,
+                  interpret, counters=True)
+    *outs, cnt = call(*args)
+    totals = np.asarray(cnt, np.int64).reshape(-1, len(K.COUNTERS)).sum(0)
+    return outs, dict(zip(K.COUNTERS, map(int, totals)))
+
+
+def numeric_counts(a: CSR, b: CSR, cap_c: int, *, schedule,
+                   table_size: int, vector: bool = False,
+                   interpret: bool | None = None) -> dict[str, int]:
+    """``kernel.COUNTERS`` of one numeric run of the planned kernel
+    (``schedule=(offsets, bin_tsize)`` and its static ``table_size``).
+    They depend on the pattern only, so one run counts every product of
+    the same structure.  Counters are int32 per member."""
+    return _counted(True, a, b, cap_c, schedule, table_size, vector,
+                    interpret)[1]

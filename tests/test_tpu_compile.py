@@ -107,6 +107,25 @@ def test_hash_kernel_compiles_at_smoke_sizes(one_chip, m, nnz_a, nnz_c,
     _check(call.lower(*args).compile())
 
 
+@pytest.mark.parametrize("vector", (False, True), ids=("hash", "hash_vector"))
+@pytest.mark.parametrize("m,nnz_a,nnz_c,table", [
+    (M, NNZ_A, NNZ_C, TABLE), (1 << 18, 4_194_176, 67_073_501, 1 << 10)],
+    ids=("g500_s16", "er_s18"))
+def test_counted_hash_numeric_compiles_at_smoke_sizes(one_chip, m, nnz_a,
+                                                      nnz_c, table, vector):
+    """``counters=True``: the counter scratch in SMEM and its extra output,
+    DMA'd out whole at each member's last bin."""
+    from repro.kernels.spgemm_hash import kernel as K
+    lens, args = _hash_args(one_chip, True, m, nnz_a)
+    out_len = K.stream_len(nnz_c)
+    call = K.numeric_call(1, N_BINS, m, lens, out_len,
+                          (0, 0, 0, 0, out_len), table, vector, False,
+                          counters=True)
+    compiled = call.lower(*args).compile()
+    _check(compiled)
+    assert compiled.out_info[-1].shape == (K.CWIN,)
+
+
 def test_batched_grid_hash_numeric_compiles(one_chip):
     """The ``custom_vmap`` rule's form: four row shards of the scale-16
     product over a ``(4, n_bins)`` grid, with B shared (stride 0)."""
