@@ -5,18 +5,22 @@ A TPU kernel may read and write single scalars only in SMEM, and SMEM is
 (``memory_space=pl.ANY``) and the kernel sees it through a small SMEM
 window that a DMA refills when an index leaves it.  Output that is
 produced one scalar at a time goes out the same way: through an SMEM
-staging window that is DMA'd to HBM when full.
+staging window that is DMA'd to HBM when full (:class:`Stream`).  Output
+produced a 128-lane vector at a time goes out through a VMEM stage of
+whole rows instead (:class:`RowStream`), so its entries are never taken
+out of the vector one scalar at a time.
 
 1-D int32/f32 arrays in HBM are tiled by ``ALIGN`` words, so every DMA
 here starts at a multiple of ``ALIGN`` and moves a whole number of tiles;
 callers pad operands with :func:`padded_len`.
 
-Both take an optional ``counter = (counter_ref, slot)``: an SMEM int32
+All three take an optional ``counter = (counter_ref, slot)``: an SMEM int32
 vector whose word ``slot`` counts the DMAs they issue (window refills,
 stream windows written).  Without it they emit no counting code.
 """
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
@@ -120,10 +124,13 @@ class Stream:
 
         @pl.when(base < self.end)
         def _write():
-            _copy_all([(s, h.at[pl.ds(base, self.win)])
-                       for h, s in zip(self.hbm, self.stage)],
-                      self.sems, self.sem0)
+            _copy_all(self._window(base), self.sems, self.sem0)
             count(self.counter)
+
+    def _window(self, base):
+        """``(stage, HBM slice)`` pairs of the window at word ``base``."""
+        return [(s, h.at[pl.ds(base, self.win)])
+                for h, s in zip(self.hbm, self.stage)]
 
     def push(self, *vals):
         """Append one value to each of the stream's arrays."""
@@ -137,3 +144,61 @@ class Stream:
         for s, v in zip(self.stage, vals):
             s[fill & (self.win - 1)] = v
         self.state[0] = fill + 1
+
+
+class RowStream(Stream):
+    """An append-only output stream to HBM through a VMEM stage of
+    ``LANES``-wide rows, fed a row of values at a time.
+
+    Same contract as :class:`Stream`, but each stage is a ``(win // LANES
+    + 1, LANES)`` VMEM ref and each HBM array is viewed as ``(len // LANES,
+    LANES)``.  :meth:`push` appends the first ``n`` lanes of a ``(1,
+    LANES)`` vector: rotated to the fill's lane, masked into the fill's row
+    and stored whole into the next, so the last row is spare.  A full
+    window is written out before the next push, and the spare row carried
+    to row 0; :meth:`close` ends the stream.
+    """
+
+    def __init__(self, hbm, stage, *args, **kwargs):
+        super().__init__(hbm, stage, *args, **kwargs)
+        self.lanes = stage[0].shape[1]
+        self.rows = self.win // self.lanes
+
+    def _window(self, base):
+        """The stage less its spare row, against the HBM rows at word
+        ``base``."""
+        row0 = pl.multiple_of(base // self.lanes, self.rows)
+        return [(s.at[pl.ds(0, self.rows)], h.at[pl.ds(row0, self.rows)])
+                for h, s in zip(self.hbm, self.stage)]
+
+    def _advance(self):
+        self.flush()
+        for s in self.stage:
+            s[pl.ds(0, 1), :] = s[pl.ds(self.rows, 1), :]
+        self.state[0] = self.state[0] - self.win
+        self.state[1] = self.state[1] + self.win
+
+    def push(self, n, *vals):
+        """Append lanes ``[0, n)`` of each ``(1, LANES)`` vector in
+        ``vals`` to the stream's arrays (``0 < n <= LANES``)."""
+        @pl.when(self.state[0] >= self.win)
+        def _full():
+            self._advance()
+
+        fill = self.state[0]
+        row, lane0 = fill // self.lanes, fill % self.lanes
+        lane = jax.lax.broadcasted_iota(jnp.int32, (1, self.lanes), 1)
+        for s, v in zip(self.stage, vals):
+            v = pltpu.roll(v, lane0, 1)
+            pltpu.store(s.at[pl.ds(row, 1)], v, mask=lane >= lane0)
+            s[pl.ds(row + 1, 1), :] = v
+        self.state[0] = fill + n
+
+    def close(self):
+        """Write what the stream still holds: a full window, then the
+        window its spare row opens."""
+        @pl.when(self.state[0] > self.win)
+        def _full():
+            self._advance()
+
+        self.flush()

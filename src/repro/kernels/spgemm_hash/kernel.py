@@ -24,19 +24,26 @@ Faithful structure, TPU-resident state:
     (section 2: the two-phase method gives exact output capacity);
   * output rows are emitted **unsorted** (C8) in table-scan order; sorting
     is an explicit epilogue owned by the caller (Table 1 "Any/Select").
+    The numeric flush moves a whole table row at a time: the occupied
+    lanes are compacted to the front of the row, in lane order, by
+    log-step rolls (:func:`_compact`, eight rows at once as one tile),
+    and appended to the output as one vector.
 
 Memory plumbing (what the TPU compiler admits):
 
   * CSR operands and outputs stay in HBM (``memory_space=pl.ANY``).  The
     kernel reads them through small SMEM *windows* that are DMA'd in when
-    an index leaves the window, and writes its output through an SMEM
-    staging window that is DMA'd out when full.  Scalars therefore only
-    ever move between SMEM and the scalar unit; VMEM is touched only by
-    whole 128-lane rows of the hash table.
+    an index leaves the window.  Output leaves through a staging window
+    that is DMA'd out when full: SMEM for the symbolic kernel's one count
+    per row (``_smem.Stream``), VMEM rows of 128 lanes for the numeric
+    kernel's compacted table rows (``_smem.RowStream``), whose HBM outputs
+    are viewed as ``(len / 128, 128)``.  Scalars therefore only ever move
+    between SMEM and the scalar unit; VMEM is touched only by whole
+    128-lane rows of the hash table and of the output stage.
   * 1-D HBM arrays are tiled by ``ALIGN`` words, so every DMA starts at a
     multiple of ``ALIGN`` and moves a multiple of it (``kernels._smem``).
     ``ops.py`` pads the operands to whole windows; output streams are
-    padded likewise and sliced back by ``ops.py``.
+    padded likewise and reshaped and sliced back by ``ops.py``.
   * Only the bin schedule (``offsets``, ``bin_tsize``) is scalar-prefetched,
     so SMEM use is independent of the matrix size.
   * Bins of one member run in order on one core (``"arbitrary"``), and the
@@ -62,7 +69,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels._smem import ALIGN, Stream, Window, _copy_all, count
+from repro.kernels._smem import (ALIGN, RowStream, Stream, Window,
+                                 _copy_all, count)
 
 #: Knuth multiplicative constant (wraps mod 2^32; int32 two's complement).
 HASH_CONST = -1640531527   # == 2654435761 mod 2^32 (Python int -> inlined literal)
@@ -73,8 +81,9 @@ CHUNK = 8
 #: Lanes of one table row: the width of one probe step.
 LANES = 128
 
-#: SMEM window sizes (words): the A-entry group (and the indptr of A), the
-#: B-row group, and the output staging window.
+#: Window sizes (words): the A-entry group (and the indptr of A), the
+#: B-row group (SMEM), and the output staging window (SMEM for the
+#: symbolic kernel, ``WIN_OUT // LANES`` VMEM rows for the numeric one).
 WIN_A = 2048
 WIN_B = 1024
 WIN_OUT = 2048
@@ -83,12 +92,13 @@ EMPTY = -1
 
 #: The counter vector of a counted kernel, in order: probe-loop steps,
 #: table rows cleared per row of A (the numeric flush scans as many),
-#: refills of the indptr, A-entry and B-row windows, and output windows
-#: written to HBM.
+#: refills of the indptr, A-entry and B-row windows, output windows
+#: written to HBM, and table rows the numeric flush compacts and writes
+#: (those holding an entry).
 COUNTERS = ("probe_steps", "table_rows", "ip_loads", "a_loads", "b_loads",
-            "out_flushes")
-PROBE_STEPS, TABLE_ROWS, IP_LOADS, A_LOADS, B_LOADS, OUT_FLUSHES = \
-    range(len(COUNTERS))
+            "out_flushes", "flush_rows")
+(PROBE_STEPS, TABLE_ROWS, IP_LOADS, A_LOADS, B_LOADS, OUT_FLUSHES,
+ FLUSH_ROWS) = range(len(COUNTERS))
 #: Words of one member's counter vector (SMEM and output): one HBM tile.
 CWIN = ALIGN
 
@@ -132,6 +142,35 @@ def _clear(tkey_ref, tval_ref, n_rows, rows):
     if rows >= 8:
         jax.lax.fori_loop(0, n8, blk, 0)
     jax.lax.fori_loop(n8 * 8, n_rows, one, 0)
+
+
+def _compact(key, val):
+    """Move the occupied lanes (``key != EMPTY``) of each ``(rows, LANES)``
+    table row to its front, keeping their order; lanes past them are
+    EMPTY keys and unspecified values.
+
+    An occupied lane moves left by the number of empty lanes before it
+    (a log-step prefix count), one bit of that distance per step, lowest
+    first.  Two entries never land on one lane: the distances they have
+    moved so far differ by at most the empty lanes between them.
+    """
+    lane = _lane()
+    emp = (key == EMPTY).astype(jnp.int32)
+    before = emp
+    for k in range(LANES.bit_length() - 1):
+        s = 1 << k
+        before = before + jnp.where(lane >= s, pltpu.roll(before, s, 1), 0)
+    dist = before - emp
+    for k in range(LANES.bit_length() - 1):
+        s = 1 << k
+        go = (key != EMPTY) & ((dist & s) != 0)
+        # lane p takes what moves out of lane p + s
+        k_in = pltpu.roll(jnp.where(go, key, EMPTY), LANES - s, 1)
+        take = k_in != EMPTY
+        key = jnp.where(take, k_in, jnp.where(go, EMPTY, key))
+        val = jnp.where(take, pltpu.roll(val, LANES - s, 1), val)
+        dist = jnp.where(take, pltpu.roll(dist, LANES - s, 1), dist)
+    return key, val
 
 
 def _probe(tkey_ref, key, tsz, row_mask, vector):
@@ -214,8 +253,9 @@ def _hash_kernel(off_ref, tsz_ref, *refs, numeric, vector, n_bins, m,
     bw = Window(*b_group, wbase, 2, sems, 1 + n_a, WIN_B, ctr(B_LOADS))
     # member e owns output words [e * s_out, (e + 1) * s_out): a numeric
     # stream past cap_c is truncated there, never written into member e + 1
-    out = Stream(outs, stage, sstate, sems, 1 + n_a + n_b, WIN_OUT,
-                 end=(e + 1) * s_out, counter=ctr(OUT_FLUSHES))
+    out = (RowStream if numeric else Stream)(
+        outs, stage, sstate, sems, 1 + n_a + n_b, WIN_OUT,
+        end=(e + 1) * s_out, counter=ctr(OUT_FLUSHES))
     for w in (ipw, aw, bw):
         w.reset()
     ip0, a0, b0 = e * s_ip, e * s_a, e * s_b
@@ -235,6 +275,9 @@ def _hash_kernel(off_ref, tsz_ref, *refs, numeric, vector, n_bins, m,
     tsz = jnp.minimum(tsz_ref[es * n_bins + b], jnp.int32(table_size))
     n_rows = jnp.maximum(tsz >> 7, 1)
     lane = _lane()
+    # table rows the numeric flush compacts together, as one (8, 128) tile
+    grp = min(8, row_mask + 1)
+    sub = jax.lax.broadcasted_iota(jnp.int32, (grp, 1), 0)
 
     def do_row(i, _):
         _clear(tkey_ref, tval_ref, n_rows, row_mask + 1)
@@ -271,25 +314,25 @@ def _hash_kernel(off_ref, tsz_ref, *refs, numeric, vector, n_bins, m,
             out.push(cnt)
             return 0
 
-        # Flush occupied slots in table order -> **unsorted** columns (C8).
-        # Only this bin's [0, tsz) prefix can be occupied.
-        def flush_row(r, c):
-            kr = tkey_ref[pl.ds(r & row_mask, 1), :]
-            vr = tval_ref[pl.ds(r & row_mask, 1), :]
-            occ = kr != EMPTY
-            n_occ = jnp.sum(occ.astype(jnp.int32))
+        # Flush occupied slots in table order -> **unsorted** columns (C8),
+        # one compacted table row at a time.  Only this bin's [0, tsz)
+        # prefix can be occupied; rows past it are masked off.
+        def flush_group(g, c):
+            rows = pl.ds(pl.multiple_of((g * grp) & row_mask, grp), grp)
+            kt = jnp.where(sub + g * grp < n_rows, tkey_ref[rows, :], EMPTY)
+            n_occ = jnp.sum((kt != EMPTY).astype(jnp.int32), axis=1,
+                            keepdims=True)
+            kt, vt = _compact(kt, tval_ref[rows, :])
+            for k in range(grp):
+                n_k = jnp.sum(jnp.where(sub == k, n_occ, 0))
 
-            def take(_, prev):
-                ln = jnp.min(jnp.where(occ & (lane > prev), lane, LANES))
-                pick = lane == ln
-                out.push(jnp.sum(jnp.where(pick, kr, 0)),
-                         jnp.sum(jnp.where(pick, vr, 0.0)))
-                return ln
-
-            jax.lax.fori_loop(0, n_occ, take, jnp.int32(-1))
+                @pl.when(n_k > 0)
+                def _emit():
+                    count(ctr(FLUSH_ROWS))
+                    out.push(n_k, kt[k:k + 1], vt[k:k + 1])
             return c
 
-        jax.lax.fori_loop(0, n_rows, flush_row, 0)
+        jax.lax.fori_loop(0, (n_rows + grp - 1) // grp, flush_group, 0)
         return 0
 
     jax.lax.fori_loop(lo, hi, do_row, 0)
@@ -302,7 +345,9 @@ def _hash_kernel(off_ref, tsz_ref, *refs, numeric, vector, n_bins, m,
                 out.push(jnp.int32(0))
                 return c
             jax.lax.fori_loop(hi, m, pad, 0)
-        out.flush()
+            out.flush()
+        else:
+            out.close()
         if counters:
             _copy_all([(cnt_ref, cnt_hbm.at[pl.ds(
                 pl.multiple_of(e * CWIN, CWIN), CWIN)])], sems, 0)
@@ -324,8 +369,9 @@ def _hash_call(numeric: bool, n_members: int, n_bins: int, m: int,
     Operands (all flat, 1-D): ``offsets`` and ``bin_tsize`` (scalar
     prefetch), then ``indptr_a, a_bs, a_bl[, a_val], b_idx[, b_val]`` in
     HBM.  Returns ``row_nnz`` (symbolic) or ``(cols, vals)`` (numeric),
-    each ``n_members * out_len`` long; ``counters=True`` appends the
-    ``n_members * CWIN`` counter output (member ``e``'s :data:`COUNTERS`
+    each ``n_members * out_len`` words (the numeric ones shaped
+    ``(n_members * out_len // LANES, LANES)``); ``counters=True`` appends
+    the ``n_members * CWIN`` counter output (member ``e``'s :data:`COUNTERS`
     at ``e * CWIN``).
     """
     kernel = functools.partial(
@@ -337,13 +383,15 @@ def _hash_call(numeric: bool, n_members: int, n_bins: int, m: int,
     n_b = 2 if numeric else 1
     n_out = 2 if numeric else 1
     rows = table_rows(table_size)
+    stage_rows = (WIN_OUT // LANES + 1, LANES)      # the last is spare
     scratch = ([smem((WIN_A,), jnp.int32)]
                + [smem((WIN_A,), jnp.int32)] * 2
                + ([smem((WIN_A,), jnp.float32)] if numeric else [])
                + [smem((WIN_B,), jnp.int32)]
                + ([smem((WIN_B,), jnp.float32)] if numeric else [])
-               + [smem((WIN_OUT,), jnp.int32)]
-               + ([smem((WIN_OUT,), jnp.float32)] if numeric else [])
+               + ([pltpu.VMEM(stage_rows, dt) for dt in (jnp.int32,
+                                                          jnp.float32)]
+                  if numeric else [smem((WIN_OUT,), jnp.int32)])
                + [smem((3,), jnp.int32), smem((2,), jnp.int32),
                   pltpu.VMEM((rows, LANES), jnp.int32)]
                + ([pltpu.VMEM((rows, LANES), jnp.float32)] if numeric
@@ -353,8 +401,9 @@ def _hash_call(numeric: bool, n_members: int, n_bins: int, m: int,
     any_spec = pl.BlockSpec(memory_space=pl.ANY)
     n_in = 1 + n_a + n_b
     dtypes = [jnp.int32, jnp.float32][:n_out]
-    out_shape = [jax.ShapeDtypeStruct((n_members * out_len,), dt)
-                 for dt in dtypes]
+    out_shape = [jax.ShapeDtypeStruct(
+        (n_members * out_len // LANES, LANES) if numeric
+        else (n_members * out_len,), dt) for dt in dtypes]
     if counters:
         out_shape.append(jax.ShapeDtypeStruct((n_members * CWIN,),
                                               jnp.int32))

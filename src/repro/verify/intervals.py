@@ -519,19 +519,42 @@ class JaxprAnalyzer:
                            f"axis {ax} of {list(shape)}", GUARDED, start, dim)
 
     # -- Pallas refs ----------------------------------------------------
-    def _indexer_dims(self, eqn, invals) -> Optional[List[Tuple[Any, int]]]:
-        """Pairs of (index abstract value | static Slice, dim size) per
-        indexed axis, from the state primitive's NDIndexer tree."""
-        ref_shape = _aval_shape(eqn.invars[0])
+    def _ref_operands(self, eqn, invals):
+        """``(indexers, stored)`` of a state primitive: its NDIndexers with
+        the abstract index values in place (None where unreadable), and
+        the value a swap or addupdate stores (None otherwise).
+        ``get``/``swap``/``addupdate`` carry the indexers as a ``tree``
+        after the ref and the stored value;
+        Pallas's ``masked_load``/``masked_swap`` flatten ``(ref,
+        transforms, value or mask, ...)`` as one ``args_tree``."""
+        prim = eqn.primitive.name
+        args_tree = eqn.params.get("args_tree")
+        if args_tree is not None:
+            try:
+                _, transforms, third, _ = args_tree.unflatten(invals)
+            except Exception:
+                return None, None
+            return transforms, third if prim == "masked_swap" else None
+        stored = invals[1] if prim in ("swap", "addupdate") \
+            and len(invals) > 1 else None
         tree = eqn.params.get("tree")
         if tree is None:
-            return None
+            return None, stored
         n_idx = tree.num_leaves
         # swap carries the stored value after the ref; indices follow.
         idx_vals = invals[len(invals) - n_idx:] if n_idx else []
         try:
             obj = jax.tree_util.tree_unflatten(tree, idx_vals)
         except Exception:
+            return None, stored
+        return obj, stored
+
+    def _indexer_dims(self, eqn, invals) -> Optional[List[Tuple[Any, int]]]:
+        """Pairs of (index abstract value | static Slice, dim size) per
+        indexed axis, from the state primitive's NDIndexers."""
+        ref_shape = _aval_shape(eqn.invars[0])
+        obj, _ = self._ref_operands(eqn, invals)
+        if obj is None:
             return None
         indexers = obj if isinstance(obj, (tuple, list)) else (obj,)
         out: List[Tuple[Any, int]] = []
@@ -589,7 +612,8 @@ class JaxprAnalyzer:
     def _ref_swap(self, eqn, invals) -> Ival:
         self._check_ref_access(eqn, invals, write=True)
         ref = invals[0]
-        stored = invals[1] if len(invals) > 1 and _is_ival(invals[1]) else TOP
+        stored = self._ref_operands(eqn, invals)[1]
+        stored = stored if _is_ival(stored) else TOP
         if isinstance(ref, RefState):
             old = ref.val
             ref.val = ref.val.join(stored)

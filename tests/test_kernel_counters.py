@@ -4,7 +4,8 @@ A counted run must give the same outputs, bit for bit, as an uncounted
 one, and each counter must equal a plain-Python walk of the kernel's
 algorithm: the same multiply hash, the same row-granular probe order
 (linear or chunked), the same ``ALIGN``-tile window refills clamped to
-the operand's end, and the same output staging windows.  Inputs are the
+the operand's end, the same output staging windows, and the same table
+rows holding an entry, which the numeric flush writes.  Inputs are the
 planned schedules of R-MAT graphs of both of the benchmark's families,
 and of one product made to collide: a B row of 200 even columns, whose
 keys all hash to one row of a 256-slot chunked table (the hash keeps a
@@ -121,7 +122,7 @@ def walk(a, b, plan, numeric, vector, cap_c=None):
     bw = _Window(K.WIN_B, padded_len(b.cap, K.WIN_B))
     cap_c = plan.cap_c if cap_c is None else cap_c
     out = _Stream(K.stream_len(cap_c if numeric else m))
-    steps = rows = 0
+    steps = rows = flush_rows = 0
     for lo, hi, tsize in zip(offsets[:-1], offsets[1:], bin_tsize):
         for w in (ipw, aw, bw):
             w.reset()
@@ -139,7 +140,9 @@ def walk(a, b, plan, numeric, vector, cap_c=None):
                     bw.read(t)
                     steps += _probe(table, int(idx_b[t]), tsz, vector)
             if numeric:
-                out.push(sum(k != K.EMPTY for row in table for k in row))
+                filled = [sum(k != K.EMPTY for k in row) for row in table]
+                flush_rows += sum(n > 0 for n in filled)
+                out.push(sum(filled))
             else:
                 out.push()
     if not numeric:
@@ -147,7 +150,7 @@ def walk(a, b, plan, numeric, vector, cap_c=None):
     out.flush()
     return {"probe_steps": steps, "table_rows": rows,
             "ip_loads": ipw.loads, "a_loads": aw.loads, "b_loads": bw.loads,
-            "out_flushes": out.flushes}
+            "out_flushes": out.flushes, "flush_rows": flush_rows}
 
 
 def _uncounted(numeric, a, b, plan, vector, cap_c):

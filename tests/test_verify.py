@@ -143,6 +143,28 @@ def test_interval_engine_flags_out_of_bounds_store():
     assert bad[0].index == (8, 11)
 
 
+@pytest.mark.parametrize("offset", (0, 8), ids=("in_bounds", "out_of_bounds"))
+def test_interval_engine_reads_masked_store_indices(offset):
+    """A masked store (``pltpu.store(..., mask=)``, the ``masked_swap``
+    primitive) flattens its indexers with the value and the mask; its
+    slice start is checked like a plain store's."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    def kernel(o_ref):
+        i = pl.program_id(0)
+        pltpu.store(o_ref.at[pl.ds(i + offset, 1)],
+                    jnp.ones((1,), jnp.float32),
+                    mask=jnp.ones((1,), jnp.bool_))
+
+    analyzer = _analyze_kernel(kernel, grid=4, out_len=8)
+    bad = [s for s in analyzer.sites if s.status == VIOLATION]
+    if offset:
+        assert [s.index for s in bad] == [(8, 11)]
+    else:
+        assert not bad
+        assert any(s.status == "proved" for s in analyzer.sites)
+
+
 def test_ival_arithmetic_basics():
     a, b = Ival(0, 3), Ival(2, 5)
     assert a.join(b).lo == 0 and a.join(b).hi == 5
