@@ -53,6 +53,7 @@ import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 from jax import shard_map
 
+from repro import tracing
 from .formats import CSR
 from .plan import SpGEMMPlan, plan_spgemm, structure_key, cache_lookup, \
     cache_store
@@ -437,6 +438,35 @@ class DistributedPlan:
         parts = jax.tree.map(lambda *xs: jnp.stack(xs), *outs)
         return ShardedCSR(parts, self.row_starts, self.shape_a[0])
 
+    def kernel_counts(self, a_sh: ShardedCSR, b: CSR
+                      ) -> list[dict[str, int]]:
+        """Run the numeric hash kernel once per shard with its counters on
+        and return one dict of ``kernels.spgemm_hash.kernel.COUNTERS`` per
+        shard.  Each run is the one the mesh executor hands that shard's
+        chip -- its rows of A, its slice of the stacked schedule, the
+        uniform static capacities -- run shard by shard on one device, like
+        :meth:`execute_shards_host`.  The counts depend on the structure
+        only.  Raises ``ValueError`` for a plan that runs no hash kernel
+        (no stacked hash schedule) and where a shard's ``total_flop``
+        reaches 2^31, past the counters' int32."""
+        if self.hash_sched is None:
+            raise ValueError(f"plan {self.algorithm!r} ({self.semiring}, "
+                             f"masked={self.mask_sh is not None}) does not "
+                             "run the hash kernel")
+        if any(p.total_flop >= 2 ** 31 for p in self.plans):
+            raise ValueError("a shard's total_flop would overflow the "
+                             "kernel's int32 counters")
+        self.check_structure(a_sh, b)
+        from repro.kernels.spgemm_hash import ops as hash_ops
+        dev = jax.devices()[0]
+        b = jax.device_put(b, dev)
+        offsets, bin_tsize, _ = self.hash_sched
+        vector = self.algorithm == "hash_vector"
+        return [hash_ops.numeric_counts(
+            jax.device_put(a_sh.local(s), dev), b, self.cap_c,
+            schedule=(offsets[s], bin_tsize[s]), table_size=self.table_size,
+            vector=vector) for s in range(len(self.plans))]
+
 
 def _memoized_executor(plan, cache_key, build):
     """Jitted executor cache on a frozen plan dataclass, keyed by whatever
@@ -451,6 +481,15 @@ def _memoized_executor(plan, cache_key, build):
         fn = jax.jit(build())
         cache[cache_key] = fn
     return fn
+
+
+def _shard_flops(a_sh: ShardedCSR, b: CSR) -> list[int]:
+    """Partial products of each shard of A times B (host, int64)."""
+    b_len = np.diff(np.asarray(b.indptr, np.int64))
+    ip = np.asarray(a_sh.parts.indptr, np.int64)
+    ind = np.asarray(a_sh.parts.indices)
+    return [int(b_len[ind[s, :ip[s, -1]]].sum())
+            for s in range(a_sh.n_shards)]
 
 
 def sharded_structure_key(sh: ShardedCSR) -> bytes:
@@ -476,6 +515,7 @@ def sharded_structure_key(sh: ShardedCSR) -> bytes:
     return digest
 
 
+@tracing.span("repro.plan_1d")
 def plan_spgemm_1d(a_sh: ShardedCSR, b: CSR, *, algorithm: str = "auto",
                    semiring: str | Semiring = "plus_times",
                    mask: CSR | ShardedCSR | None = None,
@@ -490,6 +530,11 @@ def plan_spgemm_1d(a_sh: ShardedCSR, b: CSR, *, algorithm: str = "auto",
     plan is cached in the shared LRU under one blake2b digest of all shard
     structures + B + mask + partition + semantic fields, so a repeat
     product on the same structures replans nothing.
+
+    Records the span ``repro.plan_1d`` around the whole call, cache lookup
+    included, and inside it ``repro.plan_1d.shards`` (the per-shard
+    planning, which holds the shards' own ``repro.plan`` spans) and
+    ``repro.plan_1d.freeze`` (stacked schedules, capacities, cache store).
     """
     sr = resolve_semiring(semiring)
     mask_sh = None
@@ -507,88 +552,98 @@ def plan_spgemm_1d(a_sh: ShardedCSR, b: CSR, *, algorithm: str = "auto",
         if hit is not None:
             return hit
 
-    a_locals = [a_sh.local(s) for s in range(S)]
-    mask_locals = [mask_sh.local(s) for s in range(S)] if mask_sh else None
-    algo = algorithm
-    plans = []
-    for s in range(S):
-        p = plan_spgemm(a_locals[s], b, algorithm=algo, semiring=sr.name,
-                        mask=mask_locals[s] if mask_locals else None,
-                        complement_mask=complement_mask,
-                        sorted_output=sorted_output, n_bins=n_bins,
-                        use_case="dist", cache=cache)
-        if algo == "auto":
-            algo = p.algorithm              # shard 0 resolves; rest uniform
-        plans.append(p)
+    with tracing.span("repro.plan_1d.shards"):
+        a_locals = [a_sh.local(s) for s in range(S)]
+        mask_locals = ([mask_sh.local(s) for s in range(S)] if mask_sh
+                       else None)
+        algo = algorithm
+        plans = []
+        # One static ESC expansion bound for every shard, the largest
+        # shard's exact flop: the shards share every other shape of
+        # ``symbolic``, so its one program serves them all, where each
+        # shard's own flop would compile it once per shard.  Not rounded
+        # up: the largest expansion, which sets the planning chip's peak,
+        # stays exact.
+        flop_cap = max(max(_shard_flops(a_sh, b)), 1)
+        for s in range(S):
+            p = plan_spgemm(a_locals[s], b, algorithm=algo, semiring=sr.name,
+                            mask=mask_locals[s] if mask_locals else None,
+                            complement_mask=complement_mask,
+                            sorted_output=sorted_output, n_bins=n_bins,
+                            use_case="dist", cache=cache, _flop_cap=flop_cap)
+            if algo == "auto":
+                algo = p.algorithm          # shard 0 resolves; rest uniform
+            plans.append(p)
 
-    # Freeze the per-shard hash schedules as stacked arrays: shards are
-    # padded to a uniform ``rows_cap`` (flat trailing indptr) and share
-    # ``n_bins``, so every shard's (offsets, bin_tsize, indptr_c) is
-    # shape-uniform and stacks along the shard axis -- exactly what
-    # ``shard_map`` needs to hand each chip its own schedule.  Each
-    # shard's bin sizes were clamped against its *own* table at plan
-    # time, so the uniform static ``table_size`` (the shard max) is inert
-    # and per-shard results stay bitwise the per-shard planned results.
-    table_size = 0
-    hash_sched = None
-    if algo in ("hash", "hash_vector") and mask_sh is None and \
-            sr.name == "plus_times":
-        table_size = max(p.table_size for p in plans)
-        hash_sched = (jnp.stack([p.offsets for p in plans]),
-                      jnp.stack([p.bin_tsize for p in plans]),
-                      jnp.stack([p.indptr_c for p in plans]))
+    with tracing.span("repro.plan_1d.freeze"):
+        # Freeze the per-shard hash schedules as stacked arrays: shards are
+        # padded to a uniform ``rows_cap`` (flat trailing indptr) and share
+        # ``n_bins``, so every shard's (offsets, bin_tsize, indptr_c) is
+        # shape-uniform and stacks along the shard axis -- exactly what
+        # ``shard_map`` needs to hand each chip its own schedule.  Each
+        # shard's bin sizes were clamped against its *own* table at plan
+        # time, so the uniform static ``table_size`` (the shard max) is inert
+        # and per-shard results stay bitwise the per-shard planned results.
+        table_size = 0
+        hash_sched = None
+        if algo in ("hash", "hash_vector") and mask_sh is None and \
+                sr.name == "plus_times":
+            table_size = max(p.table_size for p in plans)
+            hash_sched = (jnp.stack([p.offsets for p in plans]),
+                          jnp.stack([p.bin_tsize for p in plans]),
+                          jnp.stack([p.indptr_c for p in plans]))
 
-    # Freeze the per-shard propagation-blocking geometry the same way
-    # (DESIGN.md section 18).  PB's bucket layout is per-shard (each
-    # shard's flop total picks its own bucket width), so the shards are
-    # first re-planned with a forced common bucket count -- every shard
-    # sees the same ``n_cols``, so a common count yields one common p2
-    # width -- then padded to the max bucket capacity / output capacity.
-    # Pad lanes sit beyond ``bucket_nnz`` and are never read by either
-    # the Pallas pair or the jnp twin.  Mask pruning happened at plan
-    # time (structural), so the masked product still runs the mask-free
-    # kernels; general semirings keep ``pb_sched=None`` and the SPMD body
-    # substitutes esc.
-    cap_c_u = _pad8(max(p.cap_c for p in plans))
-    pb_sched = None
-    if algo == "pb" and sr.name == "plus_times":
-        from .pb import plan_pb
-        nb = max(p.pb_plan.n_buckets for p in plans)
-        pbs = [plan_pb(a_locals[s], b, semiring=sr.name,
-                       mask=mask_locals[s] if mask_locals else None,
-                       complement_mask=complement_mask, n_buckets=nb,
-                       cache=cache) for s in range(S)]
-        assert all(q.n_buckets == pbs[0].n_buckets for q in pbs)
-        bcap = max(q.bucket_cap for q in pbs)
+        # Freeze the per-shard propagation-blocking geometry the same way
+        # (DESIGN.md section 18).  PB's bucket layout is per-shard (each
+        # shard's flop total picks its own bucket width), so the shards are
+        # first re-planned with a forced common bucket count -- every shard
+        # sees the same ``n_cols``, so a common count yields one common p2
+        # width -- then padded to the max bucket capacity / output capacity.
+        # Pad lanes sit beyond ``bucket_nnz`` and are never read by either
+        # the Pallas pair or the jnp twin.  Mask pruning happened at plan
+        # time (structural), so the masked product still runs the mask-free
+        # kernels; general semirings keep ``pb_sched=None`` and the SPMD body
+        # substitutes esc.
+        cap_c_u = _pad8(max(p.cap_c for p in plans))
+        pb_sched = None
+        if algo == "pb" and sr.name == "plus_times":
+            from .pb import plan_pb
+            nb = max(p.pb_plan.n_buckets for p in plans)
+            pbs = [plan_pb(a_locals[s], b, semiring=sr.name,
+                           mask=mask_locals[s] if mask_locals else None,
+                           complement_mask=complement_mask, n_buckets=nb,
+                           cache=cache) for s in range(S)]
+            assert all(q.n_buckets == pbs[0].n_buckets for q in pbs)
+            bcap = max(q.bucket_cap for q in pbs)
 
-        def lanes(x, cap):   # pad trailing lane axis to the shard max
-            x = np.asarray(x)
-            return np.pad(x, [(0, 0)] * (x.ndim - 1) +
-                          [(0, cap - x.shape[-1])])
+            def lanes(x, cap):   # pad trailing lane axis to the shard max
+                x = np.asarray(x)
+                return np.pad(x, [(0, 0)] * (x.ndim - 1) +
+                              [(0, cap - x.shape[-1])])
 
-        pb_sched = (
-            jnp.stack([jnp.asarray(lanes(q.src_a, bcap)) for q in pbs]),
-            jnp.stack([jnp.asarray(lanes(q.src_b, bcap)) for q in pbs]),
-            jnp.stack([jnp.asarray(lanes(q.seg, bcap)) for q in pbs]),
-            jnp.stack([q.bucket_nnz for q in pbs]),
-            jnp.stack([q.indptr_c for q in pbs]),
-            jnp.stack([jnp.asarray(lanes(q.cols_c, cap_c_u))
-                       for q in pbs]))
+            pb_sched = (
+                jnp.stack([jnp.asarray(lanes(q.src_a, bcap)) for q in pbs]),
+                jnp.stack([jnp.asarray(lanes(q.src_b, bcap)) for q in pbs]),
+                jnp.stack([jnp.asarray(lanes(q.seg, bcap)) for q in pbs]),
+                jnp.stack([q.bucket_nnz for q in pbs]),
+                jnp.stack([q.indptr_c for q in pbs]),
+                jnp.stack([jnp.asarray(lanes(q.cols_c, cap_c_u))
+                           for q in pbs]))
 
-    plan = DistributedPlan(
-        key=key, row_starts=a_sh.row_starts, algorithm=algo,
-        semiring=sr.name, complement_mask=complement_mask,
-        sorted_output=sorted_output, mask_sh=mask_sh, shape_a=a_sh.shape,
-        shape_b=b.shape, cap_a=a_sh.cap_per, cap_b=b.cap,
-        nnz_b=int(b.nnz), plans=tuple(plans),
-        cap_c=cap_c_u,
-        flop_cap=max(max(p.flop_cap for p in plans), 1),
-        row_cap=max(p.row_cap for p in plans),
-        k_width=max(p.k_width for p in plans),
-        nnz_c=sum(p.nnz_c for p in plans),
-        table_size=table_size, hash_sched=hash_sched, pb_sched=pb_sched)
-    if cache:
-        cache_store(key, plan)
+        plan = DistributedPlan(
+            key=key, row_starts=a_sh.row_starts, algorithm=algo,
+            semiring=sr.name, complement_mask=complement_mask,
+            sorted_output=sorted_output, mask_sh=mask_sh, shape_a=a_sh.shape,
+            shape_b=b.shape, cap_a=a_sh.cap_per, cap_b=b.cap,
+            nnz_b=int(b.nnz), plans=tuple(plans),
+            cap_c=cap_c_u,
+            flop_cap=max(max(p.flop_cap for p in plans), 1),
+            row_cap=max(p.row_cap for p in plans),
+            k_width=max(p.k_width for p in plans),
+            nnz_c=sum(p.nnz_c for p in plans),
+            table_size=table_size, hash_sched=hash_sched, pb_sched=pb_sched)
+        if cache:
+            cache_store(key, plan)
     return plan
 
 

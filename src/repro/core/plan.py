@@ -360,7 +360,8 @@ def plan_spgemm(a: CSR, b: CSR, *, algorithm: str = "auto",
                 n_bins: int = 8, cache: bool = True,
                 bucket_caps: bool = False, a_row_nnz=None,
                 autotune: bool = False, autotune_db=None,
-                block: Tuple[int, int] = (8, 8)) -> SpGEMMPlan:
+                block: Tuple[int, int] = (8, 8),
+                _flop_cap: Optional[int] = None) -> SpGEMMPlan:
     """Run the full inspection once and freeze it as a :class:`SpGEMMPlan`.
 
     With ``cache=True`` (default) the structure-keyed cache is consulted
@@ -400,6 +401,12 @@ def plan_spgemm(a: CSR, b: CSR, *, algorithm: str = "auto",
     then nests a frozen :class:`repro.core.bcsr.BCSRPlan` built at
     planning time, so repeat executes stay numeric-only at both
     granularities (DESIGN.md section 17).
+
+    ``_flop_cap`` (internal, for planners of several products of one
+    shape) replaces the exact flop as ESC ``symbolic``'s static expansion
+    bound; it must be at least this product's flop.  ``symbolic``'s
+    results do not depend on the bound, so products planned with one
+    common bound share one compiled program (``plan_spgemm_1d``'s shards).
     """
     sr = resolve_semiring(semiring)
     arn_digest = None
@@ -411,7 +418,8 @@ def plan_spgemm(a: CSR, b: CSR, *, algorithm: str = "auto",
     block = tuple(block)
     key = _plan_key(a, b, mask, sr.name, complement_mask, sorted_output,
                     algorithm, use_case, n_bins) + (bucket_caps, arn_digest,
-                                                    autotune, block)
+                                                    autotune, block,
+                                                    _flop_cap)
     if cache:
         hit = cache_lookup(key)
         if hit is not None:
@@ -442,6 +450,11 @@ def plan_spgemm(a: CSR, b: CSR, *, algorithm: str = "auto",
     # Symbolic phase with the exact flop bound -- the worst-case
     # O(cap_a * min(cap_b, n)) default buffer is never allocated on replan.
     flop_cap = max(total_flop, 1)
+    if _flop_cap is not None:
+        if _flop_cap < total_flop:
+            raise ValueError(f"_flop_cap {_flop_cap} is below the product's "
+                             f"flop {total_flop}")
+        flop_cap = _flop_cap
     if bucket_caps:
         flop_cap = sched.lowest_p2(flop_cap)
     with tracing.span("repro.plan.symbolic", flop_cap=flop_cap):

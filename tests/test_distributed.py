@@ -214,6 +214,69 @@ print("OK")
 """, n_dev=8)
 
 
+def test_plan_1d_compiles_esc_once_and_keeps_exact_shard_plans():
+    """``plan_spgemm_1d`` plans every shard with one static ESC expansion
+    bound, the largest shard's flop, so ESC ``symbolic`` compiles once for
+    four shards where each shard's own exact bound compiled it four times.
+    Every shard's schedule and capacities, and the mesh product, are
+    bitwise those of plans made with each shard's own exact bound, and
+    the product equals scipy's."""
+    _run("""
+import collections
+import numpy as np, jax, scipy.sparse as sp
+from jax.sharding import Mesh
+from repro.core import plan_spgemm
+from repro.core.distributed import (shard_csr_rows, plan_spgemm_1d,
+                                    unshard_rows)
+from repro.data.rmat import rmat_csr
+compiles = collections.Counter()
+def listen(event, secs, fun_name=None, **kw):
+    if event == "/jax/core/compile/backend_compile_duration":
+        compiles[fun_name] += 1
+jax.monitoring.register_event_duration_secs_listener(listen)
+assert len(jax.devices()) == 4
+mesh = Mesh(np.array(jax.devices()), ("data",))
+a = rmat_csr(9, 16, "G500", seed=0)
+a_sh = shard_csr_rows(a, 4, b=a)
+dp = plan_spgemm_1d(a_sh, a, algorithm="hash", cache=False)
+assert compiles["jit(symbolic)"] == 1, compiles
+flops = [p.total_flop for p in dp.plans]
+assert len(set(flops)) == 4, flops
+assert {p.flop_cap for p in dp.plans} == {dp.flop_cap} == {max(flops)}
+
+exact = [plan_spgemm(a_sh.local(s), a, algorithm="hash", use_case="dist",
+                     cache=False) for s in range(4)]
+# a program per exact bound: the largest shard's is the common one
+assert compiles["jit(symbolic)"] == 4, compiles
+def same(x, y):
+    return np.asarray(x).tobytes() == np.asarray(y).tobytes()
+for p, q in zip(dp.plans, exact):
+    assert q.flop_cap == q.total_flop == p.total_flop
+    for f in ("offsets", "bin_tsize", "indptr_c", "row_nnz_c"):
+        assert same(getattr(p, f), getattr(q, f)), f
+    assert (p.cap_c, p.nnz_c, p.table_size) == (q.cap_c, q.nnz_c,
+                                                 q.table_size)
+for x, f in zip(dp.hash_sched, ("offsets", "bin_tsize", "indptr_c")):
+    assert same(x, np.stack([np.asarray(getattr(q, f)) for q in exact])), f
+
+c_sh = dp.execute(mesh, a_sh, a)
+for s, q in enumerate(exact):
+    want, got = q.execute(a_sh.local(s), a), c_sh.local(s)
+    n = int(want.nnz)
+    assert int(got.nnz) == n
+    assert same(got.indptr, want.indptr)
+    assert same(got.indices[:n], want.indices[:n])
+    assert same(got.data[:n], want.data[:n])
+c = unshard_rows(c_sh)
+ref = sp.csr_matrix(np.asarray(a.to_dense(), np.float64))
+ref = (ref @ ref).toarray()
+got = np.asarray(c.to_dense())
+assert np.array_equal(got != 0, ref != 0)
+assert np.allclose(got, ref, rtol=1e-5, atol=0)
+print("OK")
+""", n_dev=4)
+
+
 def test_distributed_summa_matches_single_node_and_honors_k_panels():
     _run("""
 import numpy as np, jax

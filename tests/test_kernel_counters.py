@@ -9,7 +9,8 @@ rows holding an entry, which the numeric flush writes.  Inputs are the
 planned schedules of R-MAT graphs of both of the benchmark's families,
 and of one product made to collide: a B row of 200 even columns, whose
 keys all hash to one row of a 256-slot chunked table (the hash keeps a
-key's parity), so probes overflow into the next row.
+key's parity), so probes overflow into the next row.  A row-sharded plan
+counts per shard, each shard as the mesh executor runs it.
 """
 import dataclasses
 
@@ -223,3 +224,28 @@ def test_kernel_counts_refuses_plans_without_the_kernel(planned):
     big = dataclasses.replace(plans["hash"], total_flop=2 ** 31)
     with pytest.raises(ValueError, match="int32"):
         big.kernel_counts(a, a)
+
+
+def test_distributed_plan_counts_each_shard_as_its_chip_runs_it():
+    """``DistributedPlan.kernel_counts`` gives one dict per row shard: the
+    walk of that shard's rows under the schedule and uniform capacities
+    the mesh executor hands its chip, which is also what the shard's own
+    plan counts.  A plan without the hash kernel is refused."""
+    from types import SimpleNamespace
+    from repro.core.distributed import plan_spgemm_1d, shard_csr_rows
+    a = rmat_csr(9, 16, "G500", seed=0)
+    a_sh = shard_csr_rows(a, 4, b=a)
+    dp = plan_spgemm_1d(a_sh, a, algorithm="hash", cache=False)
+    counts = dp.kernel_counts(a_sh, a)
+    assert len(counts) == 4
+    offsets, bin_tsize, _ = dp.hash_sched
+    for s, got in enumerate(counts):
+        a_loc = a_sh.local(s)
+        chip = SimpleNamespace(offsets=offsets[s], bin_tsize=bin_tsize[s],
+                               table_size=dp.table_size, cap_c=dp.cap_c)
+        assert got == walk(a_loc, a, chip, True, False)
+        assert got == dp.plans[s].kernel_counts(a_loc, a)
+        assert got["probe_steps"] == dp.plans[s].total_flop
+    esc = plan_spgemm_1d(a_sh, a, algorithm="esc", cache=False)
+    with pytest.raises(ValueError, match="does not run the hash kernel"):
+        esc.kernel_counts(a_sh, a)

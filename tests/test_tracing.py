@@ -1,4 +1,5 @@
-"""The program's host spans (``repro.tracing``) around ``plan_spgemm``."""
+"""The program's host spans (``repro.tracing``) around ``plan_spgemm`` and
+``plan_spgemm_1d``."""
 import glob
 import os
 
@@ -93,3 +94,47 @@ def test_spans_land_on_the_profilers_host_plane(a, tmp_path):
                for _, name, (t0, t1) in others)
     assert any(ln.startswith("tf_XLA") and s0 <= t0 <= t1 <= s1
                for ln, _, (t0, t1) in others)
+
+
+def test_plan_1d_span_nests_its_stages_and_the_shard_plans(a, tmp_path):
+    """``repro.plan_1d`` holds ``repro.plan_1d.shards``, which holds the
+    four shards' ``repro.plan`` spans, and then ``repro.plan_1d.freeze``;
+    one call each, on the profiler's host plane as in the table."""
+    from jax.profiler import ProfileData
+    from repro.core.distributed import plan_spgemm_1d, shard_csr_rows
+    a_sh = shard_csr_rows(a, 4, b=a)
+    names = ("repro.plan_1d", "repro.plan_1d.shards", "repro.plan_1d.freeze",
+             "repro.plan")
+    tracing.reset_spans()
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 1
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        plan_spgemm_1d(a_sh, a, algorithm="hash", cache=False)
+    finally:
+        jax.profiler.stop_trace()
+    totals = tracing.span_totals()
+    assert {n: totals[n].calls for n in names} == {
+        "repro.plan_1d": 1, "repro.plan_1d.shards": 1,
+        "repro.plan_1d.freeze": 1, "repro.plan": 4}
+    (path,) = glob.glob(os.path.join(str(tmp_path), "plugins", "profile",
+                                     "*", "*.xplane.pb"))
+    spans: dict = {}
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                for ev in line.events:
+                    if ev.name in names:
+                        spans.setdefault(ev.name, []).append(
+                            (ev.start_ns, ev.start_ns + ev.duration_ns))
+    assert {n: len(v) for n, v in spans.items()} == {
+        "repro.plan_1d": 1, "repro.plan_1d.shards": 1,
+        "repro.plan_1d.freeze": 1, "repro.plan": 4}
+
+    def inside(inner, outer):
+        return outer[0] <= inner[0] <= inner[1] <= outer[1]
+    (whole,), (shards,), (freeze,) = (spans[n] for n in names[:3])
+    assert inside(shards, whole) and inside(freeze, whole)
+    assert shards[1] <= freeze[0]
+    assert all(inside(p, shards) for p in spans["repro.plan"])
