@@ -20,6 +20,14 @@ Faithful structure, TPU-resident state:
       - ``vector=True`` is chunked probing (Fig. 8b): the hash names a
         *row* (a chunk of ``min(tsize, 128)`` slots) and any lane of it may
         take the key;
+  * a partial product takes the probe's first two steps at once, on the
+    vector unit alone (:func:`_insert_two_rows`): both rows are scored
+    together and the winning lane is written under a mask built from the
+    ``min`` as a vector, so nothing returns to the scalar unit per
+    partial product.  Where some insert of a row of A finds neither its
+    key nor room in its two rows, the row is cleared and redone by the
+    probe loop (:func:`_probe`); one reduction per row, read on the
+    scalar unit, decides.  Either way the table is the probe loop's;
   * two phases: ``symbolic`` counts nnz per row, ``numeric`` fills values
     (section 2: the two-phase method gives exact output capacity);
   * output rows are emitted **unsorted** (C8) in table-scan order; sorting
@@ -53,8 +61,10 @@ Memory plumbing (what the TPU compiler admits):
 Counters (``counters=True``): each member keeps an SMEM int32 vector of
 the :data:`COUNTERS`, zeroed where its stream opens and DMA'd to one
 extra output where it closes.  They depend only on the sparsity pattern
-and the schedule, never on the values.  With ``counters=False`` the
-kernel has no counter scratch, output or op.
+and the schedule, never on the values.  A counted kernel reads the
+steps of each two-row insert on the scalar unit (and drops those of a
+row it redoes, whose probe loop counts its own); with ``counters=False``
+the kernel has no counter scratch, output or op.
 
 The A side reaches the kernel pre-gathered per A entry: ``a_bs[j] =
 indptr_b[a_idx[j]]`` and ``a_bl[j]`` (that B row's length), so no random
@@ -90,15 +100,22 @@ WIN_OUT = 2048
 
 EMPTY = -1
 
-#: The counter vector of a counted kernel, in order: probe-loop steps,
-#: table rows cleared per row of A (the numeric flush scans as many),
-#: refills of the indptr, A-entry and B-row windows, output windows
-#: written to HBM, and table rows the numeric flush compacts and writes
-#: (those holding an entry).
+#: Scores of the two-row insert (:func:`_insert_two_rows`): an empty slot
+#: scores ``WINDOW`` above a hit, and a slot that is neither scores
+#: ``MISS``.
+WINDOW = 2 * LANES
+MISS = 2 * WINDOW
+
+#: The counter vector of a counted kernel, in order: probe-loop steps
+#: (those the probe loop would take, whichever insert ran), table rows
+#: cleared per row of A (the numeric flush scans as many), refills of the
+#: indptr, A-entry and B-row windows, output windows written to HBM,
+#: table rows the numeric flush compacts and writes (those holding an
+#: entry), and rows of A redone by the probe loop.
 COUNTERS = ("probe_steps", "table_rows", "ip_loads", "a_loads", "b_loads",
-            "out_flushes", "flush_rows")
+            "out_flushes", "flush_rows", "redo_rows")
 (PROBE_STEPS, TABLE_ROWS, IP_LOADS, A_LOADS, B_LOADS, OUT_FLUSHES,
- FLUSH_ROWS) = range(len(COUNTERS))
+ FLUSH_ROWS, REDO_ROWS) = range(len(COUNTERS))
 #: Words of one member's counter vector (SMEM and output): one HBM tile.
 CWIN = ALIGN
 
@@ -173,22 +190,31 @@ def _compact(key, val):
     return key, val
 
 
-def _probe(tkey_ref, key, tsz, row_mask, vector):
-    """Find ``key`` or the slot it should take; returns ``(row, lane, new,
-    steps)``, ``steps`` being the probe loop's iterations.
+def _start(key, tsz, vector):
+    """Where the probe for ``key`` starts, from the key alone: ``(live,
+    rmask, r0, first)`` -- the lanes of a table row in use, the table's
+    row mask, the first row and its first eligible lane.
 
     ``tsz`` is the (traced, p2) effective table size; tables narrower than
-    a row use the first ``tsz`` lanes of row 0.  ``row_mask`` is the static
-    scratch row mask, which keeps every row index provably in bounds.
+    a row use the first ``tsz`` lanes of row 0.
     """
-    lane = _lane()
-    live = lane < jnp.minimum(tsz, LANES)
+    live = _lane() < jnp.minimum(tsz, LANES)
     rmask = jnp.maximum(tsz >> 7, 1) - 1
     if vector:
-        r0, first = _hash(key, rmask), jnp.int32(0)
-    else:
-        h = _hash(key, tsz - 1)
-        r0, first = h >> 7, h & (LANES - 1)
+        return live, rmask, _hash(key, rmask), jnp.int32(0)
+    h = _hash(key, tsz - 1)
+    return live, rmask, h >> 7, h & (LANES - 1)
+
+
+def _probe(tkey_ref, key, tsz, row_mask, vector):
+    """Find ``key`` or the slot it should take, a row per step, for as many
+    steps as it takes; returns ``(row, lane, new, steps)``.
+
+    ``row_mask`` is the static scratch row mask, which keeps every row
+    index provably in bounds.
+    """
+    lane = _lane()
+    live, rmask, r0, first = _start(key, tsz, vector)
 
     def cond(st):
         return st[2] >= LANES
@@ -212,6 +238,67 @@ def _probe(tkey_ref, key, tsz, row_mask, vector):
     r, steps, found, new = jax.lax.while_loop(
         cond, body, (r0, jnp.int32(0), jnp.int32(LANES), jnp.int32(0)))
     return r & row_mask, found, new, steps
+
+
+def _put(tkey_ref, tval_ref, rows, krows, sels, key, prod):
+    """Give the ``sels[k]`` lanes of table row ``rows[k]`` (whose keys are
+    ``krows[k]``) the key, and add ``prod`` to their values: one product,
+    then one add, per-product rounding on the vector unit (the scipy order
+    of operations).  Every row is read before any is written, so where
+    two rows are one, the last mask must hold the lanes of both."""
+    vrows = ([tval_ref[pl.ds(r, 1), :] for r in rows]
+             if tval_ref is not None else [])
+    for r, k, sel in zip(rows, krows, sels):
+        tkey_ref[pl.ds(r, 1), :] = jnp.where(sel, key, k)
+    for r, v, sel in zip(rows, vrows, sels):
+        tval_ref[pl.ds(r, 1), :] = jnp.where(sel, v + prod, v)
+
+
+def _insert_exact(tkey_ref, tval_ref, key, prod, tsz, row_mask, vector):
+    """Insert ``key`` by the probe loop; returns ``(new, steps)``."""
+    r, found, new, steps = _probe(tkey_ref, key, tsz, row_mask, vector)
+    _put(tkey_ref, tval_ref, [r], [tkey_ref[pl.ds(r, 1), :]],
+         [_lane() == found], key, prod)
+    return new, steps
+
+
+def _insert_two_rows(tkey_ref, tval_ref, key, prod, tsz, row_mask, vector):
+    """Insert ``key`` into the two table rows the probe loop visits first,
+    ``r0`` (from lane ``first`` on) and ``r1 = r0 + 1``, on the vector unit
+    alone; returns the window's score as a ``(1, LANES)`` vector.
+
+    A slot scores its place in the window (``r0``'s lanes, then ``r1``'s),
+    plus ``WINDOW`` if it is empty rather than the key; any other slot
+    scores ``MISS``.  The min is the probe loop's choice whenever that loop
+    ends within two steps: a hit wins wherever it is (linear probing keeps
+    no empty slot before a key on its probe path; chunked probing fills
+    ``r1`` only once ``r0`` is full), else the first empty slot in probe
+    order.  Both row indices come from the key alone, and the lanes to
+    write come from the min as a vector, so nothing returns to the scalar
+    unit.  A min of ``MISS`` writes nothing: the caller redoes the row.
+    """
+    lane = _lane()
+    live, rmask, r0, first = _start(key, tsz, vector)
+    r1 = (r0 + 1) & rmask & row_mask
+    r0 = r0 & row_mask
+
+    def score(row, elig, base):
+        return jnp.where(elig & (row == key), base + lane,
+                         jnp.where(elig & (row == EMPTY),
+                                   base + lane + WINDOW, MISS))
+
+    k0 = tkey_ref[pl.ds(r0, 1), :]
+    k1 = tkey_ref[pl.ds(r1, 1), :]
+    s0 = score(k0, live & (lane >= first), 0)
+    s1 = score(k1, live, LANES)
+    s = jnp.broadcast_to(jnp.min(jnp.minimum(s0, s1), axis=1, keepdims=True),
+                         (1, LANES))
+    sel0 = (s0 == s) & (s < MISS)
+    # a table of one row has r1 == r0: the second write must keep the
+    # first's lane
+    sel1 = ((s1 == s) & (s < MISS)) | (sel0 & (r1 == r0))
+    _put(tkey_ref, tval_ref, [r0, r1], [k0, k1], [sel0, sel1], key, prod)
+    return s
 
 
 def _hash_kernel(off_ref, tsz_ref, *refs, numeric, vector, n_bins, m,
@@ -274,42 +361,64 @@ def _hash_kernel(off_ref, tsz_ref, *refs, numeric, vector, n_bins, m,
     hi = off_ref[es * (n_bins + 1) + b + 1]
     tsz = jnp.minimum(tsz_ref[es * n_bins + b], jnp.int32(table_size))
     n_rows = jnp.maximum(tsz >> 7, 1)
-    lane = _lane()
     # table rows the numeric flush compacts together, as one (8, 128) tile
     grp = min(8, row_mask + 1)
     sub = jax.lax.broadcasted_iota(jnp.int32, (grp, 1), 0)
+
+    def insert_row(p0, p1, two_rows):
+        """Insert the partial products of the row of A whose entries are
+        ``[p0, p1)``.  By the probe loop: returns the row's new keys.  By
+        the two-row insert: returns the max of the scores (the row must be
+        redone where it reaches ``MISS``), the new keys as a vector, and
+        in a counted kernel the probe loop's steps."""
+        def do_a(j, c):
+            avals = aw.read(a0 + j, a_last)
+            bs, bl = avals[0], avals[1]
+
+            def do_b(t, c):
+                bvals = bw.read(b0 + t, b_last)
+                key = bvals[0]
+                prod = (jnp.full((1, LANES), avals[2], jnp.float32) *
+                        bvals[1]) if numeric else None
+                if not two_rows:
+                    new, steps = _insert_exact(tkey_ref, tval_ref, key, prod,
+                                               tsz, row_mask, vector)
+                    count(ctr(PROBE_STEPS), steps)
+                    return c + new
+                s = _insert_two_rows(tkey_ref, tval_ref, key, prod, tsz,
+                                     row_mask, vector)
+                top, new, steps = c
+                if not numeric:
+                    new = new + ((s >= WINDOW) & (s < MISS)).astype(
+                        jnp.int32)
+                if counters:     # r1's score has bit 7 set: a second step
+                    steps = steps + 1 + jnp.max((s >> 7) & 1)
+                return jnp.maximum(top, s), new, steps
+
+            return jax.lax.fori_loop(bs, bs + bl, do_b, c)
+
+        zero = jnp.zeros((1, LANES), jnp.int32)
+        init = (zero, zero, jnp.int32(0)) if two_rows else jnp.int32(0)
+        return jax.lax.fori_loop(p0, p1, do_a, init)
 
     def do_row(i, _):
         _clear(tkey_ref, tval_ref, n_rows, row_mask + 1)
         count(ctr(TABLE_ROWS), n_rows)
         (p0,) = ipw.read(ip0 + i, ip_last)
         (p1,) = ipw.read(ip0 + i + 1, ip_last)
+        top, new, steps = insert_row(p0, p1, True)
+        # the row's one vector-to-scalar transfer: did every insert fit
+        # its two rows?  If not, redo the row by the probe loop
+        redo = jnp.max(top) >= MISS
+        count(ctr(PROBE_STEPS), jnp.where(redo, 0, steps))
 
-        def do_a(j, cnt):
-            avals = aw.read(a0 + j, a_last)
-            bs, bl = avals[0], avals[1]
+        def exact():
+            _clear(tkey_ref, tval_ref, n_rows, row_mask + 1)
+            count(ctr(REDO_ROWS))
+            return insert_row(p0, p1, False)
 
-            def do_b(t, cnt):
-                bvals = bw.read(b0 + t, b_last)
-                key = bvals[0]
-                r, found, new, steps = _probe(tkey_ref, key, tsz, row_mask,
-                                              vector)
-                count(ctr(PROBE_STEPS), steps)
-                sel = lane == found
-                row = tkey_ref[pl.ds(r, 1), :]
-                tkey_ref[pl.ds(r, 1), :] = jnp.where(sel, key, row)
-                if numeric:
-                    # one product, then one add: per-product rounding on
-                    # the vector unit (the scipy order of operations)
-                    prod = jnp.full((1, LANES), avals[2], jnp.float32) * \
-                        bvals[1]
-                    v = tval_ref[pl.ds(r, 1), :]
-                    tval_ref[pl.ds(r, 1), :] = jnp.where(sel, v + prod, v)
-                return cnt + new
-
-            return jax.lax.fori_loop(bs, bs + bl, do_b, cnt)
-
-        cnt = jax.lax.fori_loop(p0, p1, do_a, jnp.int32(0))
+        cnt = jax.lax.cond(redo, exact,
+                           lambda: jnp.int32(0) if numeric else jnp.max(new))
         if not numeric:
             out.push(cnt)
             return 0

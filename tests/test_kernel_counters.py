@@ -6,11 +6,15 @@ algorithm: the same multiply hash, the same row-granular probe order
 (linear or chunked), the same ``ALIGN``-tile window refills clamped to
 the operand's end, the same output staging windows, and the same table
 rows holding an entry, which the numeric flush writes.  Inputs are the
-planned schedules of R-MAT graphs of both of the benchmark's families,
-and of one product made to collide: a B row of 200 even columns, whose
-keys all hash to one row of a 256-slot chunked table (the hash keeps a
-key's parity), so probes overflow into the next row.  A row-sharded plan
-counts per shard, each shard as the mesh executor runs it.
+planned schedules of R-MAT graphs of both of the benchmark's families;
+of one product made to collide: a B row of 200 even columns, whose keys
+all hash to one row of a 256-slot chunked table (the hash keeps a key's
+parity), so probes overflow into the next row; and of one made to
+outgrow the two-row insert: 300 keys of one slot, in two rows of A,
+which the kernel redoes by the probe loop (``redo_rows``: the walk
+counts a row whose probes take more than two steps, and reads its
+entries again).  A row-sharded plan counts per shard, each shard as the
+mesh executor runs it.
 """
 import dataclasses
 
@@ -27,13 +31,37 @@ from repro.kernels.spgemm_hash import ops as hash_ops
 
 #: R-MAT (scale, edge factor, family) of A in A x A
 GRAPHS = {"g500_s8": (8, 16, "G500"), "er_s8": (8, 8, "ER")}
-PRODUCTS = sorted(GRAPHS) + ["even_cols"]
+PRODUCTS = sorted(GRAPHS) + ["even_cols", "one_slot"]
+#: a stride above every table size of ``one_slot``: keys ``7 + STRIDE * j``
+#: share their slot (and their chunk) in each
+STRIDE = 1 << 12
+
+
+def _one_slot():
+    """Three rows of A over four B rows: 300 keys of one slot (row 0, and
+    again after 600 spread keys in row 2), and between them two B rows of
+    700 that overlap; 2,300 B entries, so the windows refill, also while
+    a row is redone."""
+    same = 7 + STRIDE * np.arange(300)
+    b_rows = [same, np.arange(700) * 3, np.arange(350, 1050) * 3,
+              np.arange(600) * 5 + 1]
+    b = CSR.from_numpy_coo(
+        np.repeat(np.arange(4), [len(r) for r in b_rows]),
+        np.concatenate(b_rows),
+        np.linspace(0.5, 1.5, sum(map(len, b_rows)), dtype=np.float32),
+        (4, STRIDE * 300))
+    a = CSR.from_numpy_coo(np.array([0, 1, 1, 2, 2]),
+                           np.array([0, 1, 2, 3, 0]),
+                           np.float32([1, 0.5, 2, 1.5, 0.75]), (3, 4))
+    return a, b
 
 
 def _operands(name):
     if name in GRAPHS:
         a = rmat_csr(*GRAPHS[name], seed=0)
         return a, a
+    if name == "one_slot":
+        return _one_slot()
     cols = np.arange(0, 400, 2)
     a = CSR.from_numpy_coo(np.zeros(1, np.int64), np.zeros(1, np.int64),
                            np.ones(1, np.float32), (1, 1))
@@ -123,7 +151,7 @@ def walk(a, b, plan, numeric, vector, cap_c=None):
     bw = _Window(K.WIN_B, padded_len(b.cap, K.WIN_B))
     cap_c = plan.cap_c if cap_c is None else cap_c
     out = _Stream(K.stream_len(cap_c if numeric else m))
-    steps = rows = flush_rows = 0
+    steps = rows = flush_rows = redo_rows = 0
     for lo, hi, tsize in zip(offsets[:-1], offsets[1:], bin_tsize):
         for w in (ipw, aw, bw):
             w.reset()
@@ -134,12 +162,24 @@ def walk(a, b, plan, numeric, vector, cap_c=None):
             rows += n_rows
             ipw.read(i)
             ipw.read(i + 1)
+            row_steps = []
             for j in range(ip_a[i], ip_a[i + 1]):
                 aw.read(j)
                 col = idx_a[j]
                 for t in range(ip_b[col], ip_b[col + 1]):
                     bw.read(t)
-                    steps += _probe(table, int(idx_b[t]), tsz, vector)
+                    row_steps.append(_probe(table, int(idx_b[t]), tsz,
+                                            vector))
+            steps += sum(row_steps)
+            if any(n > 2 for n in row_steps):
+                # the two-row insert missed: the row is read again and
+                # redone by the probe loop
+                redo_rows += 1
+                for j in range(ip_a[i], ip_a[i + 1]):
+                    aw.read(j)
+                    col = idx_a[j]
+                    for t in range(ip_b[col], ip_b[col + 1]):
+                        bw.read(t)
             if numeric:
                 filled = [sum(k != K.EMPTY for k in row) for row in table]
                 flush_rows += sum(n > 0 for n in filled)
@@ -151,7 +191,8 @@ def walk(a, b, plan, numeric, vector, cap_c=None):
     out.flush()
     return {"probe_steps": steps, "table_rows": rows,
             "ip_loads": ipw.loads, "a_loads": aw.loads, "b_loads": bw.loads,
-            "out_flushes": out.flushes, "flush_rows": flush_rows}
+            "out_flushes": out.flushes, "flush_rows": flush_rows,
+            "redo_rows": redo_rows}
 
 
 def _uncounted(numeric, a, b, plan, vector, cap_c):
@@ -194,6 +235,8 @@ def test_counters_match_walk_and_leave_outputs_alone(planned, product,
     assert counts["probe_steps"] >= plan.total_flop
     if product == "even_cols" and vector:
         assert counts["probe_steps"] == 128 + 2 * 72
+    # only the one-slot rows outgrow the two-row insert
+    assert counts["redo_rows"] == (2 if product == "one_slot" else 0)
 
 
 def test_truncated_stream_counts_only_windows_written(planned):
